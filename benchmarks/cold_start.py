@@ -62,8 +62,8 @@ def _tune_records(M: int, S: int, D: int):
     db = ScheduleDatabase()
     tuner.set_default_db(db)
     try:
-        tuner.tuned_matmul_blocks(M, M, M, 4)
-        ops.tuned_flash_blocks(S, D, 4)
+        tuner.tuned_matmul_blocks(M, M, M, 4, "tpu_v5e")
+        ops.tuned_flash_blocks(S, D, 4, "tpu_v5e")
     finally:
         tuner.set_default_db(None)
     return db.records()
@@ -112,10 +112,10 @@ def run_benchmark(M: int = 256, S: int = 128, D: int = 64,
         tuner.set_default_cache(snapshot)
         _cold_state()
         t0 = time.perf_counter()
-        out_u = ops.matmul(x, y, force_pallas=True)
+        out_u = ops.matmul(x, y, target="tpu_v5e")
         _sync(out_u)
         t1 = time.perf_counter()
-        att_u = ops.attention(q, q, q, force_pallas=True)
+        att_u = ops.attention(q, q, q, target="tpu_v5e")
         _sync(att_u)
         t2 = time.perf_counter()
         traces_u = ops.pallas_trace_counts()
@@ -130,10 +130,10 @@ def run_benchmark(M: int = 256, S: int = 128, D: int = 64,
         t0 = time.perf_counter()
         ops.use_kernel_bundle(bundle_info.path)
         t_load = time.perf_counter()
-        out_b = ops.matmul(x, y, force_pallas=True)
+        out_b = ops.matmul(x, y, target="tpu_v5e")
         _sync(out_b)
         t1 = time.perf_counter()
-        att_b = ops.attention(q, q, q, force_pallas=True)
+        att_b = ops.attention(q, q, q, target="tpu_v5e")
         _sync(att_b)
         t2 = time.perf_counter()
         traces_b = ops.pallas_trace_counts()

@@ -33,7 +33,7 @@ import numpy as np
 from repro.core import cost_model, es
 from repro.core.cost_model import COST_MODEL_VERSION
 from repro.core.spaces import MatmulSpace, Space
-from repro.hw import get_target
+from repro.hw import resolve_target
 from repro.hw.target import HardwareTarget
 
 _UNSET = object()
@@ -576,15 +576,18 @@ def best_schedule(
 
 @functools.lru_cache(maxsize=256)
 def tuned_matmul_blocks(
-    M: int, N: int, K: int, dtype_bytes: int = 2, target_name: str = "tpu_v5e"
+    M: int, N: int, K: int, dtype_bytes: int = 2,
+    target_name: Optional[str] = None,
 ) -> Tuple[int, int, int]:
     """Statically tuned Pallas block sizes for a matmul — used by kernels/ops.
 
     Exhaustive over the (small) block space: this is what a production
     compilation service would run at model-compile time, on any host, with no
     TPU attached (the paper's cross-compilation requirement). Consults the
-    default schedule DB first, so a warm store makes this a pure lookup."""
-    target = get_target(target_name)
+    default schedule DB first, so a warm store makes this a pure lookup.
+    ``target_name=None`` tunes for the device JAX runs on
+    (``hw.resolve_target``)."""
+    target = resolve_target(target_name)
     space = MatmulSpace(M, N, K, dtype_bytes, target_kind="tpu")
     best, _ = best_schedule(space, target, limit=1024)
     return best["bm"], best["bn"], best["bk"]

@@ -15,7 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
+from repro.hw.tpu_v5e import TPU_V5E
+
 
 # Pallas trace counter: bumped every time matmul_pallas builds the kernel
 # (eager interpret run or inside a jit trace). An AOT-deserialized
@@ -59,6 +60,11 @@ def matmul_pallas(
         f"shape ({m},{n},{k}) not divisible by blocks ({bm},{bn},{bk})"
     )
     grid = (m // bm, n // bn, k // bk)
+    # The cost model budgets the double-buffered x/y/out blocks against the
+    # target's fast_mem_bytes. The kernel also holds the f32 accumulator and
+    # the dot's f32 result, so at the largest blocks that budget (which is
+    # also Mosaic's default scoped-VMEM limit) is exceeded: ask for both.
+    vmem = 2 * (bm * bk + bk * bn + bm * bn) * x.dtype.itemsize + 2 * bm * bn * 4
     return pl.pallas_call(
         functools.partial(_matmul_kernel, nk=grid[2]),
         grid=grid,
@@ -69,8 +75,9 @@ def matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(TPU_V5E.fast_mem_bytes, vmem + (1 << 20)),
         ),
         interpret=interpret,
     )(x, y)

@@ -3,9 +3,12 @@
 ``matmul`` / ``attention`` dispatch between the Pallas TPU kernels and the
 jnp reference paths:
 
-* on a TPU backend → Pallas with Tuna-statically-tuned block sizes;
-* on CPU (this container, and any cross-compiling host) → the jnp oracle,
-  unless ``force_pallas=True`` (interpret mode, used by tests).
+* on a TPU backend → the compiled Pallas kernel, with block sizes tuned
+  statically for the target that ``hw.DEVICE_KINDS`` maps the device to
+  (an unknown device kind is an error, never a default);
+* on any other backend → the jnp oracle, unless ``target`` names a
+  hardware target: then the Pallas kernel runs in interpret mode with
+  blocks tuned for that target (tests and CPU benchmarks do this).
 
 Tuning happens at trace time via ``core.tuner`` — pure static analysis, no
 device execution, memoised per shape (the paper's compilation-service flow).
@@ -34,8 +37,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import op_registry, tuner
-from repro.core.tuner import rank_space, tuned_matmul_blocks
-from repro.hw import get_target
+from repro.core.tuner import tuned_matmul_blocks
+from repro.hw import resolve_target
 from repro.kernels import ref
 from repro.kernels import flash_attention as _flash_mod
 from repro.kernels import matmul as _matmul_mod
@@ -110,12 +113,13 @@ def _bundle_executable(kernel: str, args, params: Optional[Dict] = None):
 
 @functools.lru_cache(maxsize=256)
 def tuned_flash_blocks(
-    s: int, d: int, dtype_bytes: int = 2, target_name: str = "tpu_v5e"
+    s: int, d: int, dtype_bytes: int = 2, target_name: Optional[str] = None
 ) -> Tuple[int, int]:
     """Static block_q/block_k choice for flash attention: score the induced
     (q·kᵀ then p·v) tile working set over the registry's ``flash`` space
-    (whose knobs are exactly this kernel's grid)."""
-    target = get_target(target_name)
+    (whose knobs are exactly this kernel's grid). ``target_name=None``
+    tunes for the device JAX runs on (``hw.resolve_target``)."""
+    target = resolve_target(target_name)
     db = tuner.get_default_db()
     space = op_registry.make_space(
         "flash", {"s": s, "d": d, "dtype_bytes": dtype_bytes}, target.kind)
@@ -167,23 +171,21 @@ def matmul(
     y: jax.Array,
     *,
     blocks: Optional[Tuple[int, int, int]] = None,
-    force_pallas: bool = False,
+    target: Optional[str] = None,
 ) -> jax.Array:
-    """Tuna-tuned blocked matmul."""
+    """Tuna-tuned blocked matmul (see the module docstring for dispatch)."""
     m, k = x.shape
     _, n = y.shape
-    use_pallas = _on_tpu() or force_pallas
-    if not use_pallas:
+    on_tpu = _on_tpu()
+    if not (on_tpu or target):
         return ref.matmul(x, y)
     if blocks is None:
         fn = _bundle_executable("matmul", (x, y))
         if fn is not None:
             return fn(x, y)
-        blocks = tuned_matmul_blocks(m, n, k, x.dtype.itemsize)
+        blocks = tuned_matmul_blocks(m, n, k, x.dtype.itemsize, target)
     bm, bn, bk = blocks
-    return matmul_pallas(
-        x, y, bm=bm, bn=bn, bk=bk, interpret=not _on_tpu()
-    )
+    return matmul_pallas(x, y, bm=bm, bn=bn, bk=bk, interpret=not on_tpu)
 
 
 def attention(
@@ -194,11 +196,11 @@ def attention(
     causal: bool = True,
     scale: Optional[float] = None,
     blocks: Optional[Tuple[int, int]] = None,
-    force_pallas: bool = False,
+    target: Optional[str] = None,
 ) -> jax.Array:
-    """Tuna-tuned flash attention (falls back to the oracle off-TPU)."""
-    use_pallas = _on_tpu() or force_pallas
-    if not use_pallas:
+    """Tuna-tuned flash attention (see the module docstring for dispatch)."""
+    on_tpu = _on_tpu()
+    if not (on_tpu or target):
         return ref.attention(q, k, v, causal=causal, scale=scale)
     s, d = q.shape[-2], q.shape[-1]
     if blocks is None:
@@ -208,9 +210,9 @@ def attention(
              "scale": scale if scale is not None else d ** -0.5})
         if fn is not None:
             return fn(q, k, v)
-        blocks = tuned_flash_blocks(s, d, q.dtype.itemsize)
+        blocks = tuned_flash_blocks(s, d, q.dtype.itemsize, target)
     bq, bk = blocks
     return flash_attention_pallas(
         q, k, v, causal=causal, scale=scale, block_q=bq, block_k=bk,
-        interpret=not _on_tpu(),
+        interpret=not on_tpu,
     )

@@ -37,13 +37,6 @@ from repro.parallel import context as pctx  # noqa: E402
 from repro.parallel import sharding as sh  # noqa: E402
 
 
-def _flops_bytes(cost):
-    # jax < 0.5 wraps cost_analysis() in a one-element list
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost.get("flops", 0.0), cost.get("bytes accessed", 0.0)
-
-
 def pick_accum_steps(batch: int, seq: int, mesh, target_tokens: int = 4096) -> int:
     """Gradient-accumulation microbatching: bound per-device microbatch
     tokens so scan-boundary activations fit HBM (EXPERIMENTS §Dry-run)."""
@@ -184,7 +177,7 @@ def run_cell(
     hlo = compiled.as_text()
     coll = parse_collectives(hlo)  # while bodies counted ONCE (diagnostic)
     scaled = loop_scaled_collectives(hlo)  # trip-count corrected (§Roofline)
-    flops, acc_bytes = _flops_bytes(cost)
+    flops, acc_bytes = cost.get("flops", 0.0), cost.get("bytes accessed", 0.0)
 
     record.update(
         status="ok",

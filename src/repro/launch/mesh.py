@@ -5,25 +5,17 @@ from __future__ import annotations
 import jax
 
 
-def _axis_kwargs(n_axes: int) -> dict:
-    # jax < 0.5 has no jax.sharding.AxisType (meshes default to Auto there)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips when ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests use small ones, e.g. (2,4) on 8 host devices)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple:

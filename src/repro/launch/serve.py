@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.engine import (ContinuousEngine, Request, request_stats)
 from repro.models.model import Model
 
@@ -143,6 +144,7 @@ def serve(model: Model, params, requests: List[Request], slots: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

@@ -13,6 +13,7 @@ mesh + sharded state, grad accumulation, int8 grad compression.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import functools
 import os
@@ -27,6 +28,7 @@ from repro.checkpoint import store
 from repro.data.loader import PrefetchLoader
 from repro.data.synthetic import SyntheticConfig, SyntheticTokens
 from repro.launch import mesh as mesh_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch import steps as steps_mod
 from repro.models.model import Model
 from repro.optim import adamw
@@ -54,31 +56,45 @@ class TrainOptions:
 
 
 def build_state(model: Model, opt_cfg: adamw.AdamWConfig, seed: int, mesh=None):
-    params = model.init(jax.random.key(seed))
-    opt_state = adamw.init_state(opt_cfg, params)
-    if mesh is not None:
-        p_sh = sh.params_sharding(params, mesh)
-        o_sh = sh.opt_state_sharding(opt_state, params, mesh)
-        params = jax.tree.map(jax.device_put, params, p_sh)
-        opt_state = jax.tree.map(
-            jax.device_put, opt_state, o_sh,
-            is_leaf=lambda x: isinstance(x, dict) and "q" in x,
-        ) if opt_cfg.state_dtype == "int8" else jax.tree.map(
-            jax.device_put, opt_state, o_sh
-        )
-    return params, opt_state
+    """Parameters and optimizer state. On a mesh every leaf is made by a
+    jitted program whose outputs are already sharded, so no leaf is ever
+    whole on one device."""
+    key = jax.random.key(seed)
+    init_opt = functools.partial(adamw.init_state, opt_cfg)
+    if mesh is None:
+        params = model.init(key)
+        return params, init_opt(params)
+    p_sh = sh.params_sharding(jax.eval_shape(model.init, key), mesh)
+    params = jax.jit(model.init, out_shardings=p_sh)(key)
+    o_sh = sh.opt_state_sharding(jax.eval_shape(init_opt, params), params, mesh)
+    return params, jax.jit(init_opt, out_shardings=o_sh)(params)
+
+
+@contextlib.contextmanager
+def _mesh_context(mesh_shape):
+    """The run's mesh (None without one), current and with the activation
+    sharding rules installed for as long as the run lasts."""
+    if not mesh_shape:
+        yield None
+        return
+    mesh = mesh_mod.make_mesh(mesh_shape, ("data", "model"))
+    pctx.install(("data",), tp_size=int(mesh.shape["model"]), sp_seq=False)
+    try:
+        with jax.set_mesh(mesh):
+            yield mesh
+    finally:
+        pctx.clear()
 
 
 def train(cfg, opts: TrainOptions, injector: Optional[FailureInjector] = None,
           monitor: Optional[StragglerMonitor] = None) -> Dict[str, Any]:
+    with _mesh_context(opts.mesh_shape) as mesh:
+        return _train(cfg, opts, mesh, injector, monitor)
+
+
+def _train(cfg, opts: TrainOptions, mesh, injector, monitor) -> Dict[str, Any]:
     model = Model(cfg)
     opt_cfg = adamw.AdamWConfig(lr=opts.lr, state_dtype=opts.state_dtype)
-
-    mesh = None
-    if opts.mesh_shape:
-        mesh = mesh_mod.make_mesh(opts.mesh_shape, ("data", "model"))
-        pctx.install(("data",), tp_size=int(mesh.shape["model"]), sp_seq=False)
-
     params, opt_state = build_state(model, opt_cfg, opts.seed, mesh)
     p_sh = sh.params_sharding(params, mesh) if mesh is not None else None
     step_fn = steps_mod.make_train_step(
@@ -169,6 +185,7 @@ def train_with_recovery(cfg, opts: TrainOptions,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",

@@ -4,6 +4,7 @@ sequence-sharded).
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -11,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops as kops
 from repro.models import layers as L
+from repro.parallel import context as pctx
 
 NEG_INF = -1e30
 
@@ -106,6 +108,30 @@ def chunked_attention(
     return out.reshape(b, h, s, dh).astype(q.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def flash_attention(q, k, v, causal: bool, chunk: int) -> jax.Array:
+    """``kops.attention`` (the Pallas flash kernel on a TPU), differentiable
+    and run per shard under a mesh. The kernel has no backward pass, so its
+    gradient is that of ``chunked_attention``, the same function in jnp,
+    recomputed from q/k/v."""
+    return pctx.per_shard_attention(
+        functools.partial(kops.attention, causal=causal), q, k, v)
+
+
+def _flash_fwd(q, k, v, causal, chunk):
+    return flash_attention(q, k, v, causal, chunk), (q, k, v)
+
+
+def _flash_bwd(causal, chunk, residuals, g):
+    _, vjp = jax.vjp(
+        lambda q, k, v: chunked_attention(q, k, v, causal=causal, chunk=chunk),
+        *residuals)
+    return vjp(g)
+
+
+flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
 def attention_forward(
     cfg,
     p: Dict,
@@ -129,7 +155,7 @@ def attention_forward(
     elif s >= chunked_threshold:
         out = chunked_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk)
     else:
-        out = kops.attention(q, k, v, causal=causal)
+        out = flash_attention(q, k, v, causal, cfg.attn_chunk)
     b = x.shape[0]
     cd = cfg.jnp_compute_dtype()
     merged = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.head_dim)

@@ -12,6 +12,7 @@ batching), and (enc-dec only) cross-attention caches built at prefill.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -22,35 +23,44 @@ from repro.models import transformer as T
 from repro.parallel import context as pctx
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def init_params(cfg, rng) -> Dict:
+    """Parameters of ``cfg`` drawn from ``rng`` by one compiled program. Each
+    leaf is drawn and cast to the parameter dtype inside it, so at full
+    width no f32 copy of a stacked leaf reaches device memory, and a caller
+    can place the leaves where they belong (``out_shardings``) as they are
+    made."""
+    ks = jax.random.split(rng, 4)
+    params: Dict[str, Any] = {
+        "embed": L.init_embed(cfg, ks[0]),
+        "norm_f": L.init_norm(cfg),
+        "layers": T.init_stack(cfg, ks[1], cross=cfg.encoder_decoder),
+    }
+    if cfg.encoder_decoder:
+        enc_pattern = (("attention", "dense"),)
+        params["encoder"] = T.init_stack(
+            cfg, ks[2], n_layers=cfg.n_encoder_layers, pattern=enc_pattern
+        )
+        params["enc_norm_f"] = L.init_norm(cfg)
+        params["enc_pos"] = L.normal(
+            ks[3], (cfg.n_frontend_tokens, cfg.d_model), 0.02,
+            cfg.jnp_param_dtype(),
+        )
+    if cfg.frontend == "vision":
+        params["vis_proj"] = L.normal(
+            ks[3], (cfg.d_model, cfg.d_model), cfg.d_model ** -0.5,
+            cfg.jnp_param_dtype(),
+        )
+    return params
+
+
 @dataclasses.dataclass
 class Model:
     cfg: Any
 
     # ------------------------------------------------------------------ init
     def init(self, rng) -> Dict:
-        cfg = self.cfg
-        ks = jax.random.split(rng, 4)
-        params: Dict[str, Any] = {
-            "embed": L.init_embed(cfg, ks[0]),
-            "norm_f": L.init_norm(cfg),
-            "layers": T.init_stack(cfg, ks[1], cross=cfg.encoder_decoder),
-        }
-        if cfg.encoder_decoder:
-            enc_pattern = (("attention", "dense"),)
-            params["encoder"] = T.init_stack(
-                cfg, ks[2], n_layers=cfg.n_encoder_layers, pattern=enc_pattern
-            )
-            params["enc_norm_f"] = L.init_norm(cfg)
-            params["enc_pos"] = L.normal(
-                ks[3], (cfg.n_frontend_tokens, cfg.d_model), 0.02,
-                cfg.jnp_param_dtype(),
-            )
-        if cfg.frontend == "vision":
-            params["vis_proj"] = L.normal(
-                ks[3], (cfg.d_model, cfg.d_model), cfg.d_model ** -0.5,
-                cfg.jnp_param_dtype(),
-            )
-        return params
+        return init_params(self.cfg, rng)
 
     # --------------------------------------------------------------- helpers
     def _encode(self, params, frames):

@@ -60,6 +60,26 @@ def activation_sharding(dp_axes: Tuple[str, ...], tp_axis: str = "model",
         install(*prev) if prev[0] is not None else clear()
 
 
+def per_shard_attention(fn, q: jax.Array, k: jax.Array, v: jax.Array):
+    """``fn(q, k, v)`` on [B, H, S, dh] operands, run once per shard under
+    ``shard_map``: batch over the DP axes, heads over TP where the query and
+    KV head counts both divide it. For kernels XLA cannot partition (Mosaic
+    kernels); without an installed context and a current mesh ``fn`` runs
+    as is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if _DP_AXES is None or mesh.empty:
+        return fn(q, k, v)
+    dp = 1
+    for a in _DP_AXES:
+        dp *= mesh.shape[a]
+    tp = mesh.shape.get(_TP_AXIS, 0)
+    spec = P(_DP_AXES if q.shape[0] % dp == 0 else None,
+             _TP_AXIS if tp and q.shape[1] % tp == 0 and k.shape[1] % tp == 0
+             else None)
+    return jax.shard_map(fn, in_specs=(spec,) * 3, out_specs=spec,
+                         check_vma=False)(q, k, v)
+
+
 def constrain_dims(x: jax.Array, dims: Tuple) -> jax.Array:
     """Generic constraint: ``dims`` entries are 'dp', 'tp', or None per
     leading axis (trailing axes unconstrained). Divisibility-guarded; no-op

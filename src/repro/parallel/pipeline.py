@@ -90,26 +90,12 @@ def pipeline_apply(
         )
         return outputs
 
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(
-            staged,
-            mesh=mesh,
-            in_specs=(param_specs, x_spec),
-            out_specs=x_spec,
-            axis_names={axis},
-            check_vma=False,
-        )
-    else:  # jax < 0.5: experimental API, fully manual (partial-manual via
-        # ``auto=`` trips SPMD PartitionId there; unmentioned axes simply
-        # see replicated data, and the pipeline body only collects on
-        # ``axis``, so full manual is equivalent for this use)
-        from jax.experimental.shard_map import shard_map
-
-        mapped = shard_map(
-            staged,
-            mesh=mesh,
-            in_specs=(param_specs, x_spec),
-            out_specs=x_spec,
-            check_rep=False,
-        )
+    mapped = jax.shard_map(
+        staged,
+        mesh=mesh,
+        in_specs=(param_specs, x_spec),
+        out_specs=x_spec,
+        axis_names={axis},
+        check_vma=False,
+    )
     return mapped(stage_params, x)

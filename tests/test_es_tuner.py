@@ -57,7 +57,7 @@ class TestTuner:
         assert tile * bufs <= TPU.fast_mem_bytes
 
     def test_tuned_blocks_divide_shape(self):
-        bm, bn, bk = tuned_matmul_blocks(2048, 2048, 2048, 2)
+        bm, bn, bk = tuned_matmul_blocks(2048, 2048, 2048, 2, "tpu_v5e")
         assert 2048 % bm == 0 and 2048 % bn == 0 and 2048 % bk == 0
         # hardware-aligned tiles
         assert bn % 128 == 0 and bk % 128 == 0

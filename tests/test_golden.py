@@ -246,13 +246,13 @@ class TestBundleDispatch:
         q = jnp.asarray(RNG.standard_normal((1, 1, 128, 64)), jnp.float32)
         # baseline: same blocks via explicit blocks=, compiled the slow way
         base_mm = np.asarray(ops.matmul(x, y, blocks=(64, 64, 64),
-                                        force_pallas=True))
+                                        target="tpu_v5e"))
         base_att = np.asarray(ops.attention(q, q, q, blocks=(64, 64),
-                                            force_pallas=True))
+                                            target="tpu_v5e"))
         ops.use_kernel_bundle(binfo.path)
         ops.reset_pallas_trace_counts()
-        got_mm = np.asarray(ops.matmul(x, y, force_pallas=True))
-        got_att = np.asarray(ops.attention(q, q, q, force_pallas=True))
+        got_mm = np.asarray(ops.matmul(x, y, target="tpu_v5e"))
+        got_att = np.asarray(ops.attention(q, q, q, target="tpu_v5e"))
         counts = ops.pallas_trace_counts()
         assert counts == {"matmul": 0, "flash": 0}  # the AOT witness
         assert ops.get_kernel_bundle().exec_hits == 2
@@ -263,7 +263,7 @@ class TestBundleDispatch:
     def test_without_bundle_first_call_traces(self):
         x = jnp.ones((128, 128), jnp.float32)
         ops.reset_pallas_trace_counts()
-        ops.matmul(x, x, force_pallas=True)
+        ops.matmul(x, x, target="tpu_v5e")
         assert ops.pallas_trace_counts()["matmul"] == 1
 
     def test_tracer_args_fall_through_to_trace_path(self, built_bundle):
@@ -276,7 +276,7 @@ class TestBundleDispatch:
 
         @jax.jit
         def f(a, b):
-            return ops.matmul(a, b, force_pallas=True)
+            return ops.matmul(a, b, target="tpu_v5e")
 
         np.testing.assert_allclose(np.asarray(f(x, x)),
                                    np.asarray(x) @ np.asarray(x),
@@ -286,7 +286,7 @@ class TestBundleDispatch:
     def test_bundle_is_first_schedule_tier(self, built_bundle):
         _, _, binfo = built_bundle
         ops.use_kernel_bundle(binfo.path)
-        assert ops.tuned_flash_blocks(128, 64, 4) == (64, 64)
+        assert ops.tuned_flash_blocks(128, 64, 4, "tpu_v5e") == (64, 64)
         bundle = ops.get_kernel_bundle()
         assert bundle.hits >= 1
         rec, source = tuner._lookup(MM_OP, TGT, rec_version(), None)
@@ -336,7 +336,7 @@ class TestStaleCacheDegradeClearsMemos:
         snap = str(tmp_path / "cache.json")
         ScheduleCache.build(db.path, snap)
         tuner.set_default_cache(snap)
-        assert ops.tuned_flash_blocks(2048, 128) == (256, 128)  # memoised
+        assert ops.tuned_flash_blocks(2048, 128, 2, "tpu_v5e") == (256, 128)  # memoised
 
         obj = json.load(open(snap))
         obj["cost_model_version"] = "cm0"
@@ -349,7 +349,7 @@ class TestStaleCacheDegradeClearsMemos:
             assert tuner.get_default_cache() is None
         # memo must have been dropped with the cache: the pick re-resolves
         # to the heuristic, not the rejected snapshot's record
-        assert ops.tuned_flash_blocks(2048, 128) != (256, 128)
+        assert ops.tuned_flash_blocks(2048, 128, 2, "tpu_v5e") != (256, 128)
 
 
 class TestPublishRoundtrip:

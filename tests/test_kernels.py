@@ -87,3 +87,44 @@ class TestChunkedAttention:
         want = ref.attention(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-4)
+
+
+class TestFlashAttentionGradient:
+    """The Pallas flash kernel is forward-only; ``models.attention.
+    flash_attention`` gives it the gradient of the jnp mirror, so training
+    at prompt lengths that take the kernel can differentiate through it."""
+
+    @pytest.mark.parametrize("pallas,mesh", [(False, False), (True, False),
+                                             (True, True)])
+    def test_grad_matches_oracle(self, pallas, mesh, monkeypatch):
+        import contextlib
+        import functools
+
+        from repro.kernels import ops
+        from repro.launch.mesh import make_mesh
+        from repro.models import attention as attn
+        from repro.parallel import context as pctx
+
+        if pallas:  # the kernel, interpreted, where a TPU would compile it
+            monkeypatch.setattr(ops, "attention", functools.partial(
+                ops.attention, target="tpu_v5e"))
+        ctx = contextlib.ExitStack()
+        if mesh:  # a Mosaic kernel under a mesh has to run per shard
+            ctx.enter_context(jax.set_mesh(make_mesh((1, 1), ("data", "model"))))
+            ctx.enter_context(pctx.activation_sharding(("data",), tp_size=1))
+        q = _rand((1, 4, 128, 32), jnp.float32)
+        k = _rand((1, 2, 128, 32), jnp.float32)
+        v = _rand((1, 2, 128, 32), jnp.float32)
+        w = _rand((1, 4, 128, 32), jnp.float32)
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+        with ctx:
+            got = jax.jit(jax.grad(loss(lambda q, k, v: attn.flash_attention(
+                q, k, v, True, 64)), argnums=(0, 1, 2)))(q, k, v)
+        want = jax.grad(loss(lambda q, k, v: ref.attention(
+            q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)
+        for g, h in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(h),
+                                       atol=2e-4, rtol=2e-3)

@@ -180,7 +180,7 @@ class TestWarmCache:
         snap = str(tmp_path / "cache.json")
         ScheduleCache.build(db.path, snap)
         ops.use_schedule_cache(snap)  # clears the memo, installs the cache
-        assert ops.tuned_flash_blocks(2048, 128) == (256, 128)
+        assert ops.tuned_flash_blocks(2048, 128, 2, "tpu_v5e") == (256, 128)
         assert tuner.get_default_cache().hits >= 1
 
     def test_tuned_matmul_blocks_served_from_default_db(self, tmp_path,
@@ -195,7 +195,7 @@ class TestWarmCache:
             raise AssertionError("cost model evaluated despite warm DB")
 
         monkeypatch.setattr(cost_model, "evaluate", boom)
-        bm, bn, bk = tuner.tuned_matmul_blocks(2048, 2048, 2048, 2)
+        bm, bn, bk = tuner.tuned_matmul_blocks(2048, 2048, 2048, 2, "tpu_v5e")
         assert (bm, bn, bk) == (cfg["bm"], cfg["bn"], cfg["bk"])
 
     def test_rank_space_writes_back_best(self, tmp_path):
@@ -226,13 +226,13 @@ class TestWarmCache:
     def test_set_default_db_clears_flash_memo(self, tmp_path):
         from repro.kernels import ops
 
-        heuristic = ops.tuned_flash_blocks(1024, 128)  # memoised, no DB
+        heuristic = ops.tuned_flash_blocks(1024, 128, 2, "tpu_v5e")  # memoised, no DB
         db = ScheduleDatabase(tmp_path / "db.jsonl")
         db.add(ScheduleRecord(
             op="flash[d=128,dtype_bytes=2,s=1024]", target="tpu_v5e",
             config={"block_q": 128, "block_k": 128}, score=1e-9))
         tuner.set_default_db(db)
-        assert ops.tuned_flash_blocks(1024, 128) == (128, 128)
+        assert ops.tuned_flash_blocks(1024, 128, 2, "tpu_v5e") == (128, 128)
         assert heuristic != (128, 128)  # proves the memo was refreshed
 
 
