@@ -80,4 +80,5 @@ def matmul_pallas(
             vmem_limit_bytes=max(TPU_V5E.fast_mem_bytes, vmem + (1 << 20)),
         ),
         interpret=interpret,
+        name="matmul",
     )(x, y)

@@ -23,6 +23,15 @@ republish lands mid-traffic without waiting for a full wave to drain.
 
 Per-request measurement: TTFT (submit -> first token) and end-to-end
 latency, aggregated to p50/p95/p99 by ``latency_summary``.
+
+Host time: every iteration of ``run`` is a profiler step (``engine.step``)
+cut into host spans that nest strictly on the engine's thread (README
+"Tracing the engine"): ``engine.admit`` (``engine.refresh``,
+``engine.prefill``, ``engine.first_token``, ``engine.insert``),
+``engine.launch``, ``engine.sync`` and ``engine.emit``. The spans of a
+request carry its ``rid``. The four phases also add their seconds to
+``stats()`` (``admit_s``, ``launch_s``, ``sync_s``, ``emit_s``), which
+together cover ``run_s``.
 """
 from __future__ import annotations
 
@@ -105,6 +114,25 @@ def greedy_decode_reference(model, params, prompt: List[int], max_new: int,
     return out
 
 
+class _Phase:
+    """Host span ``name`` (``meta`` as its metadata) whose seconds are added
+    to ``counters[key]``."""
+    __slots__ = ("_t", "_counters", "_key", "_span")
+
+    def __init__(self, counters: Dict[str, float], key: str, name: str,
+                 **meta):
+        self._t = time.perf_counter()
+        self._counters, self._key = counters, key
+        self._span = jax.profiler.TraceAnnotation(name, **meta)
+
+    def __enter__(self) -> None:
+        self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        self._counters[self._key] += time.perf_counter() - self._t
+
+
 class _Slot:
     __slots__ = ("req", "deadline")
 
@@ -137,14 +165,22 @@ class ContinuousEngine:
         self.slots = slots
         self.cap = cap
         self.refresh = refresh
-        self._prefill = jax.jit(lambda p, b: model.prefill(p, b, cap))
+
+        # the programs' names (jit_prefill, jit_decode_step, jit_insert) are
+        # what a device trace shows them by
+        def prefill(params, batch):
+            return model.prefill(params, batch, cap)
+
+        def insert(live, one, i):
+            # scatter one request's prefilled cache into the live cache at
+            # slot index i: every leaf is [G, B, ...] (batch axis 1), so one
+            # dynamic_update_slice per leaf replaces the whole slot slice
+            return jax.tree.map(lambda a, b: jax.lax.dynamic_update_slice_in_dim(
+                a, b.astype(a.dtype), i, axis=1), live, one)
+
+        self._prefill = jax.jit(prefill)
         self._decode = jax.jit(model.decode_step)
-        # scatter one request's prefilled cache into the live cache at slot
-        # index i: every leaf is [G, B, ...] (batch axis 1), so one
-        # dynamic_update_slice per leaf replaces the whole slot slice
-        self._insert = jax.jit(lambda live, one, i: jax.tree.map(
-            lambda a, b: jax.lax.dynamic_update_slice_in_dim(
-                a, b.astype(a.dtype), i, axis=1), live, one))
+        self._insert = jax.jit(insert)
         self.cache = model.init_cache(slots, cap)
         self.pos = np.zeros(slots, np.int32)   # next write index per slot
         self.tok = np.zeros(slots, np.int32)   # last emitted token per slot
@@ -156,16 +192,23 @@ class ContinuousEngine:
         self.prefills = 0
         self.cache_reloads = 0
         self.deadline_truncations = 0
+        self.host_s = dict.fromkeys(
+            ("run_s", "admit_s", "launch_s", "sync_s", "emit_s"), 0.0)
         self._admitted = 0
 
     # ---------------------------------------------------------------- admit
     def _admit(self, slot_i: int, req: Request) -> None:
+        span = jax.profiler.TraceAnnotation
         prompt = np.asarray(req.prompt, np.int32)[None]
-        cache_1, pos_1, last_logits = self._prefill(
-            self.params, {"tokens": jnp.asarray(prompt)})
+        with span("engine.prefill", rid=req.rid, slot=slot_i,
+                  prompt_len=len(req.prompt)):
+            cache_1, pos_1, last_logits = self._prefill(
+                self.params, {"tokens": jnp.asarray(prompt)})
         self.prefills += 1
-        tok0 = int(jnp.argmax(last_logits[0, 0]))
-        self.cache = self._insert(self.cache, cache_1, slot_i)
+        with span("engine.first_token", rid=req.rid):
+            tok0 = int(jnp.argmax(last_logits[0, 0]))
+        with span("engine.insert", rid=req.rid, slot=slot_i):
+            self.cache = self._insert(self.cache, cache_1, slot_i)
         slot = self._slots[slot_i]
         slot.req = req
         slot.deadline = (None if req.deadline_s is None
@@ -196,33 +239,49 @@ class ContinuousEngine:
         """Serve ``requests`` to completion. Admission order is earliest
         deadline first (stable for equal/absent deadlines)."""
         self._t0 = time.perf_counter()
-        queue = sorted(
-            requests,
-            key=lambda r: (r.deadline_s if r.deadline_s is not None
-                           else float("inf")),
-        )
-        queue.reverse()  # pop() from the tail = earliest deadline
-        while queue or any(not s.free for s in self._slots):
-            # refill every free slot; the snapshot poll rides the admission
-            # boundary (not the very first batch — that snapshot was just
-            # loaded at startup)
-            admitting = queue and any(s.free for s in self._slots)
-            if admitting and self.refresh is not None and self._admitted:
-                if self.refresh():
-                    self.cache_reloads += 1
-            for i, s in enumerate(self._slots):
-                if s.free and queue:
-                    self._admit(i, queue.pop())
-            live = [i for i, s in enumerate(self._slots) if not s.free]
-            if not live:
-                continue
+        try:
+            queue = sorted(
+                requests,
+                key=lambda r: (r.deadline_s if r.deadline_s is not None
+                               else float("inf")),
+            )
+            queue.reverse()  # pop() from the tail = earliest deadline
+            step = 0
+            while queue or any(not s.free for s in self._slots):
+                with jax.profiler.StepTraceAnnotation("engine.step",
+                                                      step_num=step):
+                    step += 1
+                    self._step(queue)
+        finally:
+            self.host_s["run_s"] += time.perf_counter() - self._t0
+
+    def _step(self, queue: List[Request]) -> None:
+        """One iteration of ``run``: refill every free slot, then one decode
+        step over the live slots."""
+        # the snapshot poll rides the admission boundary (not the very first
+        # batch — that snapshot was just loaded at startup)
+        if queue and any(s.free for s in self._slots):
+            with _Phase(self.host_s, "admit_s", "engine.admit"):
+                if self.refresh is not None and self._admitted:
+                    with jax.profiler.TraceAnnotation("engine.refresh"):
+                        if self.refresh():
+                            self.cache_reloads += 1
+                for i, s in enumerate(self._slots):
+                    if s.free and queue:
+                        self._admit(i, queue.pop())
+        live = [i for i, s in enumerate(self._slots) if not s.free]
+        if not live:
+            return
+        with _Phase(self.host_s, "launch_s", "engine.launch", live=len(live)):
             logits, self.cache = self._decode(
                 self.params, self.cache,
                 jnp.asarray(self.tok), jnp.asarray(self.pos))
             self.engine_steps += 1
             self.slot_steps += len(live)
             self.wasted_slot_steps += self.slots - len(live)
+        with _Phase(self.host_s, "sync_s", "engine.sync"):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))  # one host sync
+        with _Phase(self.host_s, "emit_s", "engine.emit"):
             for i in live:
                 req = self._slots[i].req
                 req.out.append(int(nxt[i]))
@@ -236,4 +295,5 @@ class ContinuousEngine:
                 "wasted_slot_steps": self.wasted_slot_steps,
                 "prefills": self.prefills,
                 "cache_reloads": self.cache_reloads,
-                "deadline_truncations": self.deadline_truncations}
+                "deadline_truncations": self.deadline_truncations,
+                **self.host_s}

@@ -143,6 +143,17 @@ def serve(model: Model, params, requests: List[Request], slots: int,
     return stats
 
 
+def host_split(stats: Dict) -> Optional[str]:
+    """The continuous engine's host time per decode step (blocked on the
+    step's tokens, and the rest) and per admission, from its counters."""
+    steps, admits = stats.get("engine_steps"), stats.get("prefills")
+    if "sync_s" not in stats or not steps or not admits:
+        return None
+    return (f"per decode step: sync {1e3 * stats['sync_s'] / steps:.2f} ms, "
+            f"host {1e3 * (stats['launch_s'] + stats['emit_s']) / steps:.2f} "
+            f"ms; per admission {1e3 * stats['admit_s'] / admits:.2f} ms")
+
+
 def main() -> None:
     enable_compile_cache()
     ap = argparse.ArgumentParser()
@@ -234,6 +245,9 @@ def main() -> None:
           f"{stats['ttft_s']['p95']:.3f}/{stats['ttft_s']['p99']:.3f}s; "
           f"latency p50/p95/p99 = {stats['latency_s']['p50']:.3f}/"
           f"{stats['latency_s']['p95']:.3f}/{stats['latency_s']['p99']:.3f}s")
+    split = host_split(stats)
+    if split:
+        print(f"[serve] {split}")
     if cache_installed:
         from repro.core import tuner
 

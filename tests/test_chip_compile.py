@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from bench.trace import flash_shapes
 from repro.configs.base import get_config
 from repro.core.tuner import tuned_matmul_blocks
 from repro.hw import target_for_device_kind
@@ -57,6 +58,18 @@ def _no_persistent_cache():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
+def _op_text(compiled) -> str:
+    """The compiled module printed as a profiler trace names its
+    operations: operand shapes, no metadata or backend config."""
+    from jax._src.lib import _jax
+
+    opts = _jax.HloPrintOptions()
+    opts.print_metadata = False
+    opts.print_backend_config = False
+    opts.print_operand_shape = True
+    return compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+
+
 @pytest.mark.parametrize("s", [512, 600])
 def test_flash_compiles_at_yi6b_prefill(s, one_chip, target):
     cfg = get_config("yi-6b")
@@ -70,6 +83,13 @@ def test_flash_compiles_at_yi6b_prefill(s, one_chip, target):
         q, k, v, causal=True, block_q=bq, block_k=bk)).lower(
         spec(cfg.n_heads), spec(cfg.n_kv_heads), spec(cfg.n_kv_heads)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the kernel's operation text as a device trace shows it: named after
+    # the kernel, and parsed by the benchmark's flash_roofline
+    ops = [ln.strip() for ln in _op_text(compiled).splitlines()
+           if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(ops) == 1 and ops[0].startswith("%flash_attention"), ops
+    assert flash_shapes(ops[0]) == (cfg.n_heads, cfg.n_kv_heads, s,
+                                    cfg.head_dim)
 
 
 @pytest.mark.parametrize("m,n,k", [(1024, 1024, 1024), (2048, 2048, 2048),
@@ -81,3 +101,4 @@ def test_matmul_compiles_with_tuned_blocks(m, n, k, one_chip, target):
     compiled = jax.jit(lambda x, y: matmul_pallas(
         x, y, bm=bm, bn=bn, bk=bk)).lower(x, y).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "%matmul" in _op_text(compiled)
