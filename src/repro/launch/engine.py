@@ -114,6 +114,33 @@ def greedy_decode_reference(model, params, prompt: List[int], max_new: int,
     return out
 
 
+def programs(model, cap: int):
+    """The engine's three jitted programs: ``prefill(params, batch)``,
+    ``decode(params, cache, tok, pos)`` and ``insert(live, one, i)``. The
+    decode step and the insert take the live cache as donated, so each
+    writes its few rows in place and no second cache is ever allocated.
+    Their names (``jit_prefill``, ``jit_decode_step``, ``jit_insert``) are
+    what a device trace shows them by."""
+    def prefill(params, batch):
+        return model.prefill(params, batch, cap)
+
+    def insert(live, one, i):
+        # scatter one request's prefilled cache into the live cache at
+        # slot index i: every leaf is [G, B, ...] (batch axis 1), so one
+        # dynamic_update_slice per leaf replaces the whole slot slice
+        return jax.tree.map(lambda a, b: jax.lax.dynamic_update_slice_in_dim(
+            a, b.astype(a.dtype), i, axis=1), live, one)
+
+    return (jax.jit(prefill), jax.jit(model.decode_step, donate_argnums=1),
+            jax.jit(insert, donate_argnums=0))
+
+
+def _consumed(cache) -> bool:
+    """Whether the call just dispatched took ``cache`` as donated: its
+    buffers then belong to the call's result, written in place."""
+    return jax.tree.leaves(cache)[0].is_deleted()
+
+
 class _Phase:
     """Host span ``name`` (``meta`` as its metadata) whose seconds are added
     to ``counters[key]``."""
@@ -166,21 +193,7 @@ class ContinuousEngine:
         self.cap = cap
         self.refresh = refresh
 
-        # the programs' names (jit_prefill, jit_decode_step, jit_insert) are
-        # what a device trace shows them by
-        def prefill(params, batch):
-            return model.prefill(params, batch, cap)
-
-        def insert(live, one, i):
-            # scatter one request's prefilled cache into the live cache at
-            # slot index i: every leaf is [G, B, ...] (batch axis 1), so one
-            # dynamic_update_slice per leaf replaces the whole slot slice
-            return jax.tree.map(lambda a, b: jax.lax.dynamic_update_slice_in_dim(
-                a, b.astype(a.dtype), i, axis=1), live, one)
-
-        self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(model.decode_step)
-        self._insert = jax.jit(insert)
+        self._prefill, self._decode, self._insert = programs(model, cap)
         self.cache = model.init_cache(slots, cap)
         self.pos = np.zeros(slots, np.int32)   # next write index per slot
         self.tok = np.zeros(slots, np.int32)   # last emitted token per slot
@@ -192,6 +205,7 @@ class ContinuousEngine:
         self.prefills = 0
         self.cache_reloads = 0
         self.deadline_truncations = 0
+        self.cache_in_place = 0      # decodes/inserts given it as donated
         self.host_s = dict.fromkeys(
             ("run_s", "admit_s", "launch_s", "sync_s", "emit_s"), 0.0)
         self._admitted = 0
@@ -208,7 +222,9 @@ class ContinuousEngine:
         with span("engine.first_token", rid=req.rid):
             tok0 = int(jnp.argmax(last_logits[0, 0]))
         with span("engine.insert", rid=req.rid, slot=slot_i):
-            self.cache = self._insert(self.cache, cache_1, slot_i)
+            live = self.cache
+            self.cache = self._insert(live, cache_1, slot_i)
+            self.cache_in_place += _consumed(live)
         slot = self._slots[slot_i]
         slot.req = req
         slot.deadline = (None if req.deadline_s is None
@@ -273,9 +289,11 @@ class ContinuousEngine:
         if not live:
             return
         with _Phase(self.host_s, "launch_s", "engine.launch", live=len(live)):
+            live_cache = self.cache
             logits, self.cache = self._decode(
-                self.params, self.cache,
+                self.params, live_cache,
                 jnp.asarray(self.tok), jnp.asarray(self.pos))
+            self.cache_in_place += _consumed(live_cache)
             self.engine_steps += 1
             self.slot_steps += len(live)
             self.wasted_slot_steps += self.slots - len(live)
@@ -296,4 +314,5 @@ class ContinuousEngine:
                 "prefills": self.prefills,
                 "cache_reloads": self.cache_reloads,
                 "deadline_truncations": self.deadline_truncations,
+                "cache_in_place": self.cache_in_place,
                 **self.host_s}

@@ -171,69 +171,72 @@ def decode_attention(
     cache_v: jax.Array,
     pos: jax.Array,  # scalar i32 (lockstep) or [B] i32 (per-slot depths)
     cross: bool = False,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token attention over the cache; returns (y, new_k, new_v).
+) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
+    """One-token attention over the cache; returns (y, k_new, v_new).
 
     ``pos`` is the index where each new token sits. A scalar means every
     row decodes at the same depth (wave scheduling); a ``[B]`` vector gives
-    each slot its own depth (continuous batching) — RoPE angles, the cache
-    write index, and the validity mask are then all per-slot, so rows at
-    different sequence lengths share one decode launch.
+    each slot its own depth (continuous batching) — RoPE angles and the
+    validity mask are then per-slot, so rows at different sequence lengths
+    share one decode launch.
 
-    For cross-attention the cache is the (static) encoder projection and no
-    update happens. The einsums reduce over the cache's sequence axis — when
-    that axis is sharded (long-context SP), XLA turns the reductions into
-    partial sums + all-reduce: a flash-decode combine."""
+    The cache is only read: the token attends over the cache's positions
+    ``< pos`` and over its own key and value, which stand in for the cache
+    row at ``pos``. ``k_new``/``v_new`` ([B, Hkv, 1, dh], in the cache's
+    dtype) are returned for the caller to write at ``pos``
+    (``transformer.write_decode_cache``), once for every layer, in place.
+    The result equals that of writing first and attending over ``<= pos``.
+
+    For cross-attention the cache is the (static) encoder projection and
+    ``k_new``/``v_new`` are None. The einsums reduce over the cache's
+    sequence axis — when that axis is sharded (long-context SP), XLA turns
+    the reductions into partial sums + all-reduce: a flash-decode combine."""
     cd = cfg.jnp_compute_dtype()
     b = x.shape[0]
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     g = h // hkv
     cap = cache_k.shape[2]
-    vector_pos = (not cross) and pos.ndim == 1
+    idx = jnp.arange(cap)
 
     q = (x.astype(cd) @ p["wq"].astype(cd))
     if "bq" in p:
         q = q + p["bq"].astype(cd)
     q = q.reshape(b, h, dh)  # S=1 folded away
 
-    if not cross:
+    knew = vnew = None
+    keys, values = cache_k, cache_v
+    if cross:
+        valid = jnp.ones((b, 1, 1, cap), bool)
+    else:
         knew = (x.astype(cd) @ p["wk"].astype(cd))
         vnew = (x.astype(cd) @ p["wv"].astype(cd))
         if "bk" in p:
             knew, vnew = knew + p["bk"].astype(cd), vnew + p["bv"].astype(cd)
         knew = knew.reshape(b, hkv, 1, dh)
-        vnew = vnew.reshape(b, hkv, 1, dh)
-        if vector_pos:
+        vnew = vnew.reshape(b, hkv, 1, dh).astype(cache_v.dtype)
+        if pos.ndim == 1:
             # per-row tables [B, 1, dh/2]; lift to [B, 1, 1, dh/2] so they
             # broadcast over the head axis of q [B, H, 1, dh] / knew
             sin, cos = L.rope_tables(cfg, pos[:, None].astype(jnp.int32))
             sin, cos = sin[:, None], cos[:, None]
+            row = pos[:, None]  # [B, 1]: each row's own position
         else:
             sin, cos = L.rope_tables(cfg, pos[None].astype(jnp.int32))  # [1, dh/2]
+            row = pos[None, None]
         q = L.apply_rope(q.reshape(b, h, 1, dh), sin, cos).reshape(b, h, dh)
-        knew = L.apply_rope(knew, sin, cos)
-        if vector_pos:
-            upd = jax.vmap(
-                lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(c, n, i, axis=1)
-            )
-            cache_k = upd(cache_k, knew.astype(cache_k.dtype), pos)
-            cache_v = upd(cache_v, vnew.astype(cache_v.dtype), pos)
-        else:
-            cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, knew.astype(cache_k.dtype), pos, axis=2)
-            cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, vnew.astype(cache_v.dtype), pos, axis=2)
+        knew = L.apply_rope(knew, sin, cos).astype(cache_k.dtype)
+        # the new token in place of the cache row at pos, selected inside
+        # the read: no copy of the cache is made
+        at = (idx[None, :] == row)[:, None, :, None]  # [B|1, 1, cap, 1]
+        keys = jnp.where(at, knew, cache_k)
+        values = jnp.where(at, vnew, cache_v)
+        valid = (idx[None, :] <= row)[:, None, None]  # [B|1, 1, 1, cap]
 
     qg = q.reshape(b, hkv, g, dh).astype(jnp.float32) * (dh ** -0.5)
-    logits = jnp.einsum("bhgd,bhkd->bhgk", qg, cache_k.astype(jnp.float32))
-    idx = jnp.arange(cap)
-    if cross:
-        valid = jnp.ones((b, 1, 1, cap), bool)
-    elif vector_pos:
-        valid = (idx[None, :] <= pos[:, None])[:, None, None]  # [B,1,1,cap]
-    else:
-        valid = (idx <= pos)[None, None, None]
+    logits = jnp.einsum("bhgd,bhkd->bhgk", qg, keys.astype(jnp.float32))
     logits = jnp.where(valid, logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgk,bhkd->bhgd", w, cache_v.astype(jnp.float32))
+    out = jnp.einsum("bhgk,bhkd->bhgd", w, values.astype(jnp.float32))
     merged = out.reshape(b, 1, h * dh).astype(cd)
     y = merged @ p["wo"].astype(cd)
-    return y.astype(x.dtype), cache_k, cache_v
+    return y.astype(x.dtype), knew, vnew

@@ -139,38 +139,65 @@ def apply_block_decode(
     cache: Dict,
     pos: jax.Array,
 ) -> Tuple[jax.Array, Dict]:
+    """One token through one block; the block's cache is only read.
+    Returns (x, new): what the step changes in the cache — an attention
+    mixer's new key and value (``k``/``v`` [B, Hkv, 1, dh], for
+    ``write_decode_cache`` to write at ``pos``), or a recurrent mixer's
+    whole new state. Cross-attention's ``xk``/``xv`` never change."""
     mixer_kind, mlp_kind = kinds
     h = L.apply_norm(cfg, p["norm1"], x)
-    new_cache = dict(cache)
     if mixer_kind == "attention":
         y, k, v = attn.decode_attention(cfg, p["mixer"], h, cache["k"],
                                         cache["v"], pos)
-        new_cache["k"], new_cache["v"] = k, v
-        x = x + y
+        new = {"k": k, "v": v}
     elif mixer_kind == "mamba":
-        y, st = ssm_mod.mamba_decode(cfg, p["mixer"], h,
-                                     {"conv": cache["conv"], "h": cache["h"]})
-        new_cache.update(st)
-        x = x + y
+        y, new = ssm_mod.mamba_decode(cfg, p["mixer"], h,
+                                      {"conv": cache["conv"], "h": cache["h"]})
     elif mixer_kind == "mlstm":
-        y, st = xlstm_mod.mlstm_decode(
+        y, new = xlstm_mod.mlstm_decode(
             cfg, p["mixer"], h, {k_: cache[k_] for k_ in ("C", "n", "m")}
         )
-        new_cache.update(st)
-        x = x + y
     elif mixer_kind == "slstm":
-        y, st = xlstm_mod.slstm_decode(
+        y, new = xlstm_mod.slstm_decode(
             cfg, p["mixer"], h, {k_: cache[k_] for k_ in ("c", "n", "h", "m")}
         )
-        new_cache.update(st)
-        x = x + y
+    else:
+        raise ValueError(mixer_kind)
+    x = x + y
     if "xattn" in p:
         hx = L.apply_norm(cfg, p["norm_x"], x)
         y, _, _ = attn.decode_attention(cfg, p["xattn"], hx, cache["xk"],
                                         cache["xv"], pos, cross=True)
         x = x + y
     x, _aux = _apply_mlp(cfg, p, mlp_kind, x)
-    return x, new_cache
+    return x, new
+
+
+def _write_tokens(cache: jax.Array, tokens: jax.Array, pos: jax.Array) -> jax.Array:
+    """``tokens`` [G, B, Hkv, 1, dh] written into ``cache`` [G, B, Hkv, CAP,
+    dh] at each row's position: one ``dynamic_update_slice`` per row (once
+    at a scalar ``pos``), which XLA does in place in a donated cache. (For
+    the TPU, a scatter over rows and positions compiles to copies of the
+    cache instead.)"""
+    if pos.ndim == 0:
+        return jax.lax.dynamic_update_slice(cache, tokens, (0, 0, 0, pos, 0))
+    for r in range(cache.shape[1]):
+        cache = jax.lax.dynamic_update_slice(cache, tokens[:, r: r + 1],
+                                             (0, r, 0, pos[r], 0))
+    return cache
+
+
+def write_decode_cache(mixer_kind: str, cache: Dict, new: Dict,
+                       pos: jax.Array) -> Dict:
+    """A block's stacked cache after a decode step, given the step's
+    ``new`` (stacked over groups by the scan): attention keys and values go
+    in at ``pos``, a recurrent state is replaced whole, the rest (cross
+    caches) stays as it was."""
+    out = dict(cache)
+    for key, val in new.items():
+        out[key] = (_write_tokens(cache[key], val, pos)
+                    if mixer_kind == "attention" else val)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -262,17 +289,23 @@ def apply_stack_decode(
     """One decode step through the scanned stack. ``pos`` is a scalar (all
     rows at the same depth) or ``[B]`` vector (per-slot depths); it is
     closed over by the scan body and handled in ``attn.decode_attention``
-    (SSM/xLSTM mixers are position-free recurrences)."""
+    (SSM/xLSTM mixers are position-free recurrences).
+
+    The scan reads each group's cache and carries out only what the step
+    changes (``apply_block_decode``); the new keys and values of every
+    layer are written after it, so a donated cache is updated in place and
+    no layer's slice of it is copied."""
     pattern = pattern or cfg.pattern()
 
     def body(xc, inp):
         params_g, cache_g = inp
-        new_caches = []
+        new = []
         for pp, kinds in enumerate(pattern):
-            xc, nc = apply_block_decode(cfg, params_g[pp], kinds, xc,
-                                        cache_g[pp], pos)
-            new_caches.append(nc)
-        return xc, tuple(new_caches)
+            xc, n = apply_block_decode(cfg, params_g[pp], kinds, xc,
+                                       cache_g[pp], pos)
+            new.append(n)
+        return xc, tuple(new)
 
-    x, new_cache = jax.lax.scan(body, x, (stack_params, cache))
-    return x, new_cache
+    x, new = jax.lax.scan(body, x, (stack_params, cache))
+    return x, tuple(write_decode_cache(kinds[0], c, n, pos)
+                    for kinds, c, n in zip(pattern, cache, new))

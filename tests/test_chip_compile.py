@@ -20,6 +20,8 @@ from repro.hw import target_for_device_kind
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.matmul import matmul_pallas
 from repro.kernels.ops import tuned_flash_blocks
+from repro.launch.engine import programs
+from repro.models.model import Model
 
 
 @pytest.fixture(scope="module")
@@ -102,3 +104,36 @@ def test_matmul_compiles_with_tuned_blocks(m, n, k, one_chip, target):
         x, y, bm=bm, bn=bn, bk=bk)).lower(x, y).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert "%matmul" in _op_text(compiled)
+
+
+@pytest.mark.parametrize("program", ["decode", "insert"])
+@pytest.mark.parametrize("slots,cap", [(8, 2048), (4, 3648)])
+def test_engine_updates_the_cache_in_place(program, slots, cap, one_chip):
+    """The engine's decode step and insert at yi-6b width, at the shapes of
+    the benchmark's two cells: the live cache is donated and written in
+    place, so the whole cache is aliased to the output and no temporary is
+    as large as one layer's keys (a copy of a layer's slice, or of the
+    cache, would be)."""
+    cfg = get_config("yi-6b")
+    model = Model(cfg)
+
+    def abstract(make, *args):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make, *args))
+
+    cache = abstract(lambda: model.init_cache(slots, cap))
+    _, decode, insert = programs(model, cap)
+    if program == "decode":
+        tok = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+        lowered = decode.lower(abstract(model.init, jax.random.key(0)), cache,
+                               tok, tok)
+    else:
+        one = abstract(lambda: model.init_cache(1, cap))
+        lowered = insert.lower(cache, one, jax.ShapeDtypeStruct(
+            (), jnp.int32, sharding=one_chip))
+    mem = lowered.compile().memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    layer_k = slots * cfg.n_kv_heads * cap * cfg.head_dim * 2
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < layer_k

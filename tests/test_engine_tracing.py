@@ -149,6 +149,25 @@ def test_program_names(model_and_params):
         assert f"module @{name} " in low.as_text(), name
 
 
+def test_cache_written_in_place(model_and_params):
+    """Every decode step and insert takes the live cache as donated: the
+    arrays held before a run are consumed, the engine's own stay live."""
+    cfg, model, params = model_and_params
+    engine = _engine(model, params)
+    held = jax.tree.leaves(engine.cache)
+    engine.run(_requests(cfg.vocab))
+    stats = engine.stats()
+    assert stats["cache_in_place"] == stats["engine_steps"] + stats["prefills"]
+    assert stats["engine_steps"] > 0 and stats["prefills"] == len(SPEC)
+    assert all(a.is_deleted() for a in held)
+    assert not any(a.is_deleted() for a in jax.tree.leaves(engine.cache))
+    # one admission and one step: the cache held before them is consumed
+    held = jax.tree.leaves(engine.cache)
+    engine.run(_requests(cfg.vocab, [(4, 2)]))
+    assert all(a.is_deleted() for a in held)
+    assert engine.stats()["cache_in_place"] == stats["cache_in_place"] + 2
+
+
 def test_serve_reports_host_split(model_and_params):
     cfg, model, params = model_and_params
     stats = serve(model, params, _requests(cfg.vocab), SLOTS, CAP)
@@ -156,6 +175,8 @@ def test_serve_reports_host_split(model_and_params):
         assert stats[k] > 0, k
     split = host_split(stats)
     assert split.startswith("per decode step: sync ") and "per admission" in split
+    calls = stats["engine_steps"] + stats["prefills"]
+    assert split.endswith(f"cache in place in {calls} of {calls} calls")
     # the wave scheduler keeps no host-time counters
     wave = serve(model, params, _requests(cfg.vocab), SLOTS, CAP,
                  scheduler="wave")

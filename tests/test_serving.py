@@ -1,5 +1,6 @@
 """Continuous-batching engine: per-slot positions, parity, honest accounting."""
 import copy
+import dataclasses
 
 import numpy as np
 import jax
@@ -72,6 +73,138 @@ class TestVectorPos:
             params, cache, toks, jnp.full((2,), int(pos), jnp.int32))
         np.testing.assert_allclose(np.asarray(lg_s), np.asarray(lg_v),
                                    atol=1e-5, rtol=1e-5)
+
+
+# a decode step's cache writes, per mixer kind: attention (yi), attention
+# beside mamba, mLSTM beside sLSTM, and an encoder-decoder (cross caches)
+WRITE_CONFIGS = {
+    "yi_6b": lambda: get_config("yi_6b").reduced(),
+    "mamba": lambda: dataclasses.replace(
+        get_config("jamba_v01_52b").reduced(), moe=None, attn_every=2,
+        attn_offset=1, n_layers=4),
+    "xlstm": lambda: dataclasses.replace(
+        get_config("xlstm_13b").reduced(), slstm_every=2, slstm_offset=1,
+        n_layers=4),
+    "enc_dec": lambda: get_config("whisper_large_v3").reduced(),
+}
+WRITE_CAP = 12
+
+
+@pytest.fixture(scope="module", params=list(WRITE_CONFIGS))
+def write_case(request):
+    cfg = WRITE_CONFIGS[request.param]()
+    model = Model(cfg)
+    params = model.init(jax.random.key(2))
+    rng = np.random.default_rng(11)
+    seqs = rng.integers(0, cfg.vocab, (3, WRITE_CAP))
+    frames = (0.1 * rng.standard_normal((3, cfg.n_frontend_tokens,
+                                         cfg.d_model))
+              if cfg.encoder_decoder else None)
+    jitted = dataclasses.replace(model)  # the same model, its calls jitted
+    jitted.prefill = jax.jit(model.prefill, static_argnums=2)
+    jitted.decode_step = jax.jit(model.decode_step)
+    jitted.logits = jax.jit(model.logits)
+    return cfg, jitted, params, seqs, frames
+
+
+def _inputs(cfg, seqs, frames, rows, length):
+    batch = {"tokens": jnp.asarray(seqs[rows, :length], jnp.int32)}
+    if cfg.encoder_decoder:
+        batch["frames"] = jnp.asarray(frames[rows], jnp.float32)
+    return batch
+
+
+def _row_cache(model, params, seqs, frames, r, p):
+    """Row r's cache holding its first p tokens (cap WRITE_CAP): what the
+    engine's insert puts in a slot."""
+    if p:
+        return model.prefill(params, _inputs(model.cfg, seqs, frames, [r], p),
+                             WRITE_CAP)[0]
+    cache = model.init_cache(1, WRITE_CAP)
+    if not model.cfg.encoder_decoder:
+        return cache
+    # the encoder's keys and values come from the frames, not the tokens
+    enc = model.prefill(params, _inputs(model.cfg, seqs, frames, [r], 1),
+                        WRITE_CAP)[0]
+    return tuple({**c, "xk": e["xk"], "xv": e["xv"]}
+                 for c, e in zip(cache, enc))
+
+
+def _check_step(cfg, model, params, seqs, frames, cache, pos, live):
+    """One decode step at ``pos`` over ``cache``: in ``k``/``v`` it writes
+    exactly one position per row and layer and leaves every other element
+    bitwise as it was; recurrent states step; cross caches pass through;
+    the logits of the ``live`` rows match the full-sequence forward."""
+    pos = np.asarray(pos, np.int32)
+    rows_pos = np.broadcast_to(pos, jax.tree.leaves(cache)[0].shape[1:2])
+    toks = np.array([seqs[r, p] if r in live else 0
+                     for r, p in enumerate(rows_pos)], np.int32)
+    old = jax.tree.map(np.asarray, cache)
+    logits, new = model.decode_step(params, cache, jnp.asarray(toks),
+                                    jnp.asarray(pos))
+    full = model.logits(params, _inputs(cfg, seqs, frames, sorted(live),
+                                        WRITE_CAP))
+    for i, r in enumerate(sorted(live)):
+        np.testing.assert_allclose(np.asarray(logits[r]),
+                                   np.asarray(full[i, rows_pos[r]]),
+                                   atol=1e-5, rtol=1e-5)
+    # keys and values at every position, from the whole sequences; a
+    # recurrent state from the row's first pos + 1 tokens
+    whole = model.prefill(params, _inputs(cfg, seqs, frames, sorted(live),
+                                          WRITE_CAP), WRITE_CAP)[0]
+    recurrent = any(m != "attention" for m, _ in cfg.pattern())
+    refs = {r: _row_cache(model, params, seqs, frames, r, rows_pos[r] + 1)
+            for r in live if recurrent}
+    for pp, (kinds, o, n) in enumerate(zip(cfg.pattern(), old, new)):
+        assert set(n) == set(o)
+        for key in ("xk", "xv"):
+            if key in o:
+                np.testing.assert_array_equal(np.asarray(n[key]), o[key])
+        if kinds[0] == "attention":
+            for key in ("k", "v"):
+                got = np.asarray(n[key])
+                keep = np.ones(got.shape, bool)
+                for r, p in enumerate(rows_pos):
+                    keep[:, r, :, p, :] = False
+                    assert not np.array_equal(got[:, r, :, p],
+                                              o[key][:, r, :, p]), (key, r)
+                assert np.array_equal(got[keep], o[key][keep]), key
+                for i, r in enumerate(sorted(live)):
+                    p = rows_pos[r]
+                    np.testing.assert_allclose(
+                        got[:, r, :, p], np.asarray(whole[pp][key])[:, i, :, p],
+                        atol=1e-5, rtol=1e-5)
+        else:
+            for key in set(n) - {"xk", "xv"}:
+                for r, ref in refs.items():
+                    np.testing.assert_allclose(
+                        np.asarray(n[key])[:, r],
+                        np.asarray(ref[pp][key])[:, 0], atol=1e-5, rtol=1e-5)
+
+
+class TestInPlaceWrite:
+    """A decode step reads the cache and writes each row's new key and
+    value at its own position, and nothing else."""
+
+    def test_per_row_positions(self, write_case):
+        """Rows at 0, mid, cap-1, and a free slot as the engine leaves one:
+        pos 0, token 0, and what another request left in its cache."""
+        cfg, model, params, seqs, frames = write_case
+        pos = [0, 5, WRITE_CAP - 1, 0]
+        rows = [_row_cache(model, params, seqs, frames, r, p)
+                for r, p in enumerate(pos[:3])]
+        cache = jax.tree.map(lambda *a: jnp.concatenate(a, axis=1),
+                             *rows, rows[1])
+        _check_step(cfg, model, params, seqs, frames, cache, pos,
+                    live={0, 1, 2})
+
+    def test_scalar_pos(self, write_case):
+        """The wave path: every row at one position."""
+        cfg, model, params, seqs, frames = write_case
+        cache, pos, _ = model.prefill(
+            params, _inputs(cfg, seqs, frames, [0, 1, 2], 5), WRITE_CAP)
+        _check_step(cfg, model, params, seqs, frames, cache, int(pos),
+                    live={0, 1, 2})
 
 
 class TestGreedyParity:
