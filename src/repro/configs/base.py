@@ -19,9 +19,31 @@ class MoESpec:
     n_experts: int
     top_k: int
     d_expert: int  # expert FFN hidden dim
-    shared_expert: bool = False  # llama4-style always-on shared expert
-    capacity_factor: float = 1.25
+    n_shared: int = 0  # always-on shared experts (one MLP n_shared x d_expert wide)
+    scoring: str = "softmax"  # softmax | sigmoid (DeepSeek-V3)
+    select_bias: bool = False  # per-expert bias added for selection only (noaux_tc)
+    routed_scale: float = 1.0  # routed output scale (routed_scaling_factor)
     router_aux_weight: float = 0.01
+
+
+@dataclasses.dataclass(frozen=True)
+class MLASpec:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434): keys and
+    values come from one normalised latent of width ``kv_rank``; the rope
+    part of every head's key is one shared ``rope_dim``-wide key."""
+    kv_rank: int
+    nope_dim: int  # per-head query/key width without rope
+    rope_dim: int  # per-head query/key width with rope (key shared by heads)
+    v_dim: int  # per-head value width
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def cache_dim(self) -> int:
+        """What the cache holds per position and layer: latent + rope key."""
+        return self.kv_rank + self.rope_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +75,8 @@ class ArchConfig:
     moe: Optional[MoESpec] = None
     moe_every: int = 1  # MoE MLP iff i % moe_every == moe_offset
     moe_offset: int = 0
+    first_k_dense: int = 0  # leading layers with a dense MLP (DeepSeek-V3)
+    mla: Optional[MLASpec] = None  # the "mla" mixer's sizes
 
     # --- encoder/decoder & modality frontends -------------------------
     encoder_decoder: bool = False
@@ -80,7 +104,8 @@ class ArchConfig:
 
     @property
     def period(self) -> int:
-        """Block-pattern period for scan grouping (layers stacked per kind)."""
+        """Block-pattern period for scan grouping (layers stacked per kind),
+        after the ``first_k_dense`` leading layers."""
         p = 1
         if self.attn_every > 1:
             p = math.lcm(p, self.attn_every)
@@ -110,15 +135,28 @@ class ArchConfig:
     def mlp_kind(self, i: int) -> str:
         if self.d_ff == 0 and self.moe is None:
             return "none"
-        if self.moe is not None and i % self.moe_every == self.moe_offset:
+        if i < self.first_k_dense:
+            return "dense"
+        j = i - self.first_k_dense
+        if self.moe is not None and j % self.moe_every == self.moe_offset:
             return "moe"
         return "dense"
 
     def pattern(self) -> Tuple[Tuple[str, str], ...]:
-        """(mixer, mlp) for one period."""
+        """(mixer, mlp) for one period of the layers after the leading ones."""
+        k = self.first_k_dense
         return tuple(
-            (self.mixer_kind(i), self.mlp_kind(i)) for i in range(self.period)
+            (self.mixer_kind(k + i), self.mlp_kind(k + i))
+            for i in range(self.period)
         )
+
+    def lead_pattern(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, mlp) of the leading dense layers, which share one kind
+        (empty without them)."""
+        kinds = {(self.mixer_kind(i), self.mlp_kind(i))
+                 for i in range(self.first_k_dense)}
+        assert len(kinds) <= 1, kinds
+        return tuple(kinds)
 
     def param_count(self) -> int:
         """Total parameters (for 6·N·D roofline bookkeeping)."""
@@ -143,12 +181,11 @@ class ArchConfig:
                 n_experts=min(self.moe.n_experts, 4),
                 top_k=min(self.moe.top_k, 2),
                 d_expert=64,
-                capacity_factor=2.0,
             )
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            n_layers=2 * self.period,
+            n_layers=self.first_k_dense + 2 * self.period,
             n_encoder_layers=2 if self.encoder_decoder else 0,
             d_model=64,
             n_heads=4,
@@ -158,6 +195,8 @@ class ArchConfig:
             vocab=256,
             moe=moe,
             n_frontend_tokens=8 if self.frontend else 0,
+            mla=None if self.mla is None else MLASpec(
+                kv_rank=32, nope_dim=16, rope_dim=16, v_dim=16),
             ssm_state=8,
             mlstm_chunk=8,
             param_dtype="float32",
@@ -168,6 +207,10 @@ class ArchConfig:
 def _mixer_params(cfg: ArchConfig, kind: str) -> int:
     d = cfg.d_model
     hd = cfg.head_dim
+    if kind == "mla":
+        m, h = cfg.mla, cfg.n_heads
+        return (d * h * m.qk_dim + d * m.cache_dim + m.kv_rank
+                + m.kv_rank * h * (m.nope_dim + m.v_dim) + h * m.v_dim * d)
     if kind == "attention":
         qkv = d * (cfg.n_heads * hd + 2 * cfg.n_kv_heads * hd)
         return qkv + cfg.n_heads * hd * d
@@ -202,10 +245,8 @@ def _mlp_params(cfg: ArchConfig, kind: str) -> int:
         moe = cfg.moe
         mult = 3 if cfg.activation in ("swiglu", "geglu") else 2
         per_expert = mult * d * moe.d_expert
-        total = moe.n_experts * per_expert + d * moe.n_experts  # + router
-        if moe.shared_expert:
-            total += per_expert
-        return total
+        router = d * moe.n_experts + (moe.n_experts if moe.select_bias else 0)
+        return (moe.n_experts + moe.n_shared) * per_expert + router
     return 0
 
 
@@ -215,10 +256,8 @@ def _mlp_active_params(cfg: ArchConfig, kind: str) -> int:
     moe = cfg.moe
     mult = 3 if cfg.activation in ("swiglu", "geglu") else 2
     per_expert = mult * cfg.d_model * moe.d_expert
-    active = moe.top_k * per_expert + cfg.d_model * moe.n_experts
-    if moe.shared_expert:
-        active += per_expert
-    return active
+    router = cfg.d_model * moe.n_experts + (moe.n_experts if moe.select_bias else 0)
+    return (moe.top_k + moe.n_shared) * per_expert + router
 
 
 def _count_params(cfg: ArchConfig, active_only: bool = False) -> int:
@@ -257,6 +296,7 @@ ARCH_IDS = (
     "whisper_large_v3",
     "internvl2_1b",
     "xlstm_13b",
+    "moonlight_16b_a3b",
 )
 
 _ALIASES = {
@@ -270,6 +310,7 @@ _ALIASES = {
     "whisper-large-v3": "whisper_large_v3",
     "internvl2-1b": "internvl2_1b",
     "xlstm-1.3b": "xlstm_13b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

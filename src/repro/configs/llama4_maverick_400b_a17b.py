@@ -14,7 +14,7 @@ CONFIG = ArchConfig(
     vocab=202048,
     activation="swiglu",
     rope_theta=5e5,
-    moe=MoESpec(n_experts=128, top_k=1, d_expert=8192, shared_expert=True),
+    moe=MoESpec(n_experts=128, top_k=1, d_expert=8192, n_shared=1),
     moe_every=2,
     moe_offset=1,
 )

@@ -102,7 +102,6 @@ def run_cell(
         tp_size=int(mesh.shape["model"]),
         sp_seq=variant.get("sp_seq", kind in ("train", "prefill")),
         mesh=mesh if variant.get("mixer_shard_map", False) else None,
-        moe_pin=variant.get("moe_pin", False),
     )
     with mesh:
         params_s = S.abstract_params(model)

@@ -32,6 +32,13 @@ cut into host spans that nest strictly on the engine's thread (README
 request carry its ``rid``. The four phases also add their seconds to
 ``stats()`` (``admit_s``, ``launch_s``, ``sync_s``, ``emit_s``), which
 together cover ``run_s``.
+
+MoE counters: for a model with experts the decode step also adds, on the
+device, the routed pairs, the (layer, expert) pairs that received a token
+and the busiest expert's tokens per layer into a counter array held beside
+the cache; ``run`` reads it once, when it returns (``moe_pairs``,
+``moe_experts_touched``, ``moe_max_load`` in ``stats()``; 0 for a dense
+model, whose programs carry no counters).
 """
 from __future__ import annotations
 
@@ -42,6 +49,8 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.models.moe import N_STATS
 
 
 @dataclasses.dataclass
@@ -114,24 +123,54 @@ def greedy_decode_reference(model, params, prompt: List[int], max_new: int,
     return out
 
 
+def _write_slot(live, one, i):
+    """One request's prefilled cache into the live cache at slot index i:
+    every leaf is [G, B, ...] (batch axis 1), so one dynamic_update_slice
+    per leaf replaces the whole slot slice."""
+    return jax.tree.map(lambda a, b: jax.lax.dynamic_update_slice_in_dim(
+        a, b.astype(a.dtype), i, axis=1), live, one)
+
+
+def counted(model) -> bool:
+    """Whether the engine's decode state carries MoE counters."""
+    return model.cfg.moe is not None
+
+
+def init_state(model, slots: int, cap: int):
+    """The live decode state the programs take: the cache, and for a model
+    with experts (the cache, the counters [moe.N_STATS] int32)."""
+    cache = model.init_cache(slots, cap)
+    return (cache, jnp.zeros((N_STATS,), jnp.int32)) if counted(model) else cache
+
+
 def programs(model, cap: int):
     """The engine's three jitted programs: ``prefill(params, batch)``,
-    ``decode(params, cache, tok, pos)`` and ``insert(live, one, i)``. The
-    decode step and the insert take the live cache as donated, so each
-    writes its few rows in place and no second cache is ever allocated.
-    Their names (``jit_prefill``, ``jit_decode_step``, ``jit_insert``) are
-    what a device trace shows them by."""
+    ``decode(params, state, tok, pos)`` and ``insert(live, one, i)`` over
+    the state ``init_state`` makes. The decode step and the insert take the
+    live state as donated, so each writes its few rows in place and no
+    second cache is ever allocated. Their names (``jit_prefill``,
+    ``jit_decode_step``, ``jit_insert``) are what a device trace shows them
+    by. A model with experts adds the step's counters to the state's, over
+    the rows not at position 0 (a free slot pins pos 0)."""
     def prefill(params, batch):
         return model.prefill(params, batch, cap)
 
     def insert(live, one, i):
-        # scatter one request's prefilled cache into the live cache at
-        # slot index i: every leaf is [G, B, ...] (batch axis 1), so one
-        # dynamic_update_slice per leaf replaces the whole slot slice
-        return jax.tree.map(lambda a, b: jax.lax.dynamic_update_slice_in_dim(
-            a, b.astype(a.dtype), i, axis=1), live, one)
+        if counted(model):
+            return _write_slot(live[0], one, i), live[1]
+        return _write_slot(live, one, i)
 
-    return (jax.jit(prefill), jax.jit(model.decode_step, donate_argnums=1),
+    if not counted(model):
+        return (jax.jit(prefill), jax.jit(model.decode_step, donate_argnums=1),
+                jax.jit(insert, donate_argnums=0))
+
+    def decode_step(params, state, tok, pos):
+        cache, counts = state
+        logits, cache, stats = model.decode_step_counted(params, cache, tok,
+                                                         pos, pos > 0)
+        return logits, (cache, counts + stats)
+
+    return (jax.jit(prefill), jax.jit(decode_step, donate_argnums=1),
             jax.jit(insert, donate_argnums=0))
 
 
@@ -194,7 +233,7 @@ class ContinuousEngine:
         self.refresh = refresh
 
         self._prefill, self._decode, self._insert = programs(model, cap)
-        self.cache = model.init_cache(slots, cap)
+        self.cache = init_state(model, slots, cap)  # donated to each call
         self.pos = np.zeros(slots, np.int32)   # next write index per slot
         self.tok = np.zeros(slots, np.int32)   # last emitted token per slot
         self._slots = [_Slot() for _ in range(slots)]
@@ -208,6 +247,8 @@ class ContinuousEngine:
         self.cache_in_place = 0      # decodes/inserts given it as donated
         self.host_s = dict.fromkeys(
             ("run_s", "admit_s", "launch_s", "sync_s", "emit_s"), 0.0)
+        self.moe = dict.fromkeys(
+            ("moe_pairs", "moe_experts_touched", "moe_max_load"), 0)
         self._admitted = 0
 
     # ---------------------------------------------------------------- admit
@@ -270,6 +311,8 @@ class ContinuousEngine:
                     self._step(queue)
         finally:
             self.host_s["run_s"] += time.perf_counter() - self._t0
+            if counted(self.model):  # the one readback of the counters
+                self.moe = dict(zip(self.moe, np.asarray(self.cache[1]).tolist()))
 
     def _step(self, queue: List[Request]) -> None:
         """One iteration of ``run``: refill every free slot, then one decode
@@ -315,4 +358,4 @@ class ContinuousEngine:
                 "cache_reloads": self.cache_reloads,
                 "deadline_truncations": self.deadline_truncations,
                 "cache_in_place": self.cache_in_place,
-                **self.host_s}
+                **self.host_s, **self.moe}

@@ -141,16 +141,9 @@ class Model:
         x, caches, _ = T.apply_stack(cfg, params["layers"], x, pos, causal=True,
                                      enc_out=enc_out, collect_cache=True)
         x = L.apply_norm(cfg, params["norm_f"], x)
-
-        def pad_cache(leaf):
-            # attention k/v: [G, B, Hkv, S, dh] -> capacity cap on axis 3
-            if leaf.ndim == 5 and leaf.shape[3] == s_total:
-                pad = [(0, 0)] * 5
-                pad[3] = (0, cap - s_total)
-                return jnp.pad(leaf, pad)
-            return leaf
-
-        caches = jax.tree.map(pad_cache, caches)
+        # each mixer kind declares its leaves' positions axis
+        caches = tuple(T.pad_positions(kinds[0], c, cap)
+                       for kinds, c in zip(T.stack_kinds(cfg), caches))
         last_logits = L.unembed(cfg, params["embed"], x[:, -1:])
         return caches, jnp.asarray(s_total, jnp.int32), last_logits
 
@@ -159,9 +152,19 @@ class Model:
         [B] i32 (per-slot depths — continuous batching, each row attends,
         ropes and cache-writes at its own position).
         Returns (logits [B, V], new_cache)."""
+        logits, new_cache, _ = self.decode_step_counted(params, cache, token, pos)
+        return logits, new_cache
+
+    def decode_step_counted(self, params, cache, token: jax.Array,
+                            pos: jax.Array, live: Optional[jax.Array] = None):
+        """``decode_step``, and the step's MoE counters summed over layers
+        (``moe.N_STATS`` int32: routed pairs, (layer, expert) pairs with a
+        token, the busiest expert's tokens per layer), counting the rows
+        ``live`` [B] marks (all when None); zeros for a dense model."""
         cfg = self.cfg
         x = L.embed(cfg, params["embed"], token[:, None])
-        x, new_cache = T.apply_stack_decode(cfg, params["layers"], x, cache, pos)
+        x, new_cache, stats = T.apply_stack_decode(cfg, params["layers"], x,
+                                                   cache, pos, live=live)
         x = L.apply_norm(cfg, params["norm_f"], x)
         logits = L.unembed(cfg, params["embed"], x)[:, 0]
-        return logits, new_cache
+        return logits, new_cache, stats

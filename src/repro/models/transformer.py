@@ -5,6 +5,11 @@ pattern position ``i % period``; parameters for each period position are
 stacked over the ``n_layers/period`` groups and the whole stack runs as one
 ``lax.scan`` over groups (HLO size stays O(period) regardless of depth — the
 94-layer dry-runs depend on this), with per-group remat.
+
+Leading layers of another kind (``first_k_dense``, DeepSeek-V3's dense
+layers before the MoE ones) are a segment of their own, scanned before
+the repeating pattern: the stack's tuple holds the leading segment's
+entries, then the pattern's.
 """
 from __future__ import annotations
 
@@ -16,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.models import attention as attn
 from repro.models import layers as L
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models import xlstm as xlstm_mod
@@ -32,6 +38,8 @@ def init_block(cfg, rng, mixer_kind: str, mlp_kind: str, cross: bool = False) ->
     p: Dict[str, Any] = {"norm1": L.init_norm(cfg)}
     if mixer_kind == "attention":
         p["mixer"] = attn.init_attention(cfg, ks[0])
+    elif mixer_kind == "mla":
+        p["mixer"] = mla_mod.init_mla(cfg, ks[0])
     elif mixer_kind == "mamba":
         p["mixer"] = ssm_mod.init_mamba(cfg, ks[0])
     elif mixer_kind == "mlstm":
@@ -52,6 +60,14 @@ def init_block(cfg, rng, mixer_kind: str, mlp_kind: str, cross: bool = False) ->
     return p
 
 
+# The positions axis of each cache leaf of one block ([B, ...], before the
+# group axis is stacked in front), per mixer kind: prefill pads these to the
+# capacity and a decode step writes its row into them at ``pos``. A leaf not
+# named holds no positions: a recurrent state (replaced whole by a step) or
+# a cross cache (fixed by the encoder).
+CACHE_POSITIONS = {"attention": {"k": 2, "v": 2}, "mla": {"ckv": 1}}
+
+
 def init_block_cache(cfg, mixer_kind: str, batch: int, cap: int,
                      cross_len: int = 0) -> Dict:
     """Zeroed decode cache for one block."""
@@ -61,6 +77,8 @@ def init_block_cache(cfg, mixer_kind: str, batch: int, cap: int,
         hkv, dh = cfg.n_kv_heads, cfg.head_dim
         c["k"] = jnp.zeros((batch, hkv, cap, dh), dt)
         c["v"] = jnp.zeros((batch, hkv, cap, dh), dt)
+    elif mixer_kind == "mla":
+        c["ckv"] = jnp.zeros((batch, cap, cfg.mla.cache_dim), dt)
     elif mixer_kind == "mamba":
         c.update(ssm_mod.init_mamba_cache(cfg, batch, dt))
     elif mixer_kind == "mlstm":
@@ -74,14 +92,16 @@ def init_block_cache(cfg, mixer_kind: str, batch: int, cap: int,
     return c
 
 
-def _apply_mlp(cfg, p, mlp_kind, x):
+def _apply_mlp(cfg, p, mlp_kind, x, live=None):
+    """(x after the MLP, aux loss, MoE counters [moe.N_STATS])."""
+    none = jnp.zeros((), jnp.float32), jnp.zeros((moe_mod.N_STATS,), jnp.int32)
     if mlp_kind == "none":
-        return x, jnp.zeros((), jnp.float32)
+        return (x,) + none
     h = L.apply_norm(cfg, p["norm2"], x)
     if mlp_kind == "dense":
-        return x + L.apply_dense_mlp(cfg, p["mlp"], h), jnp.zeros((), jnp.float32)
-    y, aux = moe_mod.apply_moe(cfg, p["mlp"], h)
-    return x + y, aux
+        return (x + L.apply_dense_mlp(cfg, p["mlp"], h),) + none
+    y, aux, stats = moe_mod.apply_moe(cfg, p["mlp"], h, live)
+    return x + y, aux, stats
 
 
 def apply_block(
@@ -106,6 +126,9 @@ def apply_block(
             cfg.jnp_compute_dtype()
         )
         x = x + y
+    elif mixer_kind == "mla":
+        y, cache["ckv"] = mla_mod.mla_forward(cfg, p["mixer"], h, positions)
+        x = x + y
     elif mixer_kind == "mamba":
         y, st = ssm_mod.mamba_forward(cfg, p["mixer"], h, return_state=True)
         cache.update(st)
@@ -127,7 +150,7 @@ def apply_block(
             cfg.jnp_compute_dtype()
         )
         x = x + y
-    x, aux = _apply_mlp(cfg, p, mlp_kind, x)
+    x, aux, _ = _apply_mlp(cfg, p, mlp_kind, x)
     return x, cache, aux
 
 
@@ -138,18 +161,24 @@ def apply_block_decode(
     x: jax.Array,  # [B, 1, D]
     cache: Dict,
     pos: jax.Array,
-) -> Tuple[jax.Array, Dict]:
+    live: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict, jax.Array]:
     """One token through one block; the block's cache is only read.
-    Returns (x, new): what the step changes in the cache — an attention
-    mixer's new key and value (``k``/``v`` [B, Hkv, 1, dh], for
-    ``write_decode_cache`` to write at ``pos``), or a recurrent mixer's
-    whole new state. Cross-attention's ``xk``/``xv`` never change."""
+    Returns (x, new, MoE counters): what the step changes in the cache —
+    an attention mixer's new key and value (``k``/``v`` [B, Hkv, 1, dh]) or
+    a latent mixer's new row (``ckv`` [B, 1, kv_rank + rope_dim]), for
+    ``write_decode_cache`` to write at ``pos``, or a recurrent mixer's
+    whole new state. Cross-attention's ``xk``/``xv`` never change. The
+    counters count the rows ``live`` marks (all when None)."""
     mixer_kind, mlp_kind = kinds
     h = L.apply_norm(cfg, p["norm1"], x)
     if mixer_kind == "attention":
         y, k, v = attn.decode_attention(cfg, p["mixer"], h, cache["k"],
                                         cache["v"], pos)
         new = {"k": k, "v": v}
+    elif mixer_kind == "mla":
+        y, ckv = mla_mod.mla_decode(cfg, p["mixer"], h, cache["ckv"], pos)
+        new = {"ckv": ckv}
     elif mixer_kind == "mamba":
         y, new = ssm_mod.mamba_decode(cfg, p["mixer"], h,
                                       {"conv": cache["conv"], "h": cache["h"]})
@@ -169,34 +198,51 @@ def apply_block_decode(
         y, _, _ = attn.decode_attention(cfg, p["xattn"], hx, cache["xk"],
                                         cache["xv"], pos, cross=True)
         x = x + y
-    x, _aux = _apply_mlp(cfg, p, mlp_kind, x)
-    return x, new
+    x, _aux, stats = _apply_mlp(cfg, p, mlp_kind, x, live)
+    return x, new, stats
 
 
-def _write_tokens(cache: jax.Array, tokens: jax.Array, pos: jax.Array) -> jax.Array:
-    """``tokens`` [G, B, Hkv, 1, dh] written into ``cache`` [G, B, Hkv, CAP,
-    dh] at each row's position: one ``dynamic_update_slice`` per row (once
-    at a scalar ``pos``), which XLA does in place in a donated cache. (For
-    the TPU, a scatter over rows and positions compiles to copies of the
-    cache instead.)"""
+def _write_tokens(cache: jax.Array, tokens: jax.Array, pos: jax.Array,
+                  axis: int) -> jax.Array:
+    """``tokens`` [G, B, ...] (length 1 on ``axis``) written into ``cache``
+    [G, B, ...] (length CAP on ``axis``) at each row's position: one
+    ``dynamic_update_slice`` per row (once at a scalar ``pos``), which XLA
+    does in place in a donated cache. (For the TPU, a scatter over rows and
+    positions compiles to copies of the cache instead.)"""
+    start = [0] * cache.ndim
     if pos.ndim == 0:
-        return jax.lax.dynamic_update_slice(cache, tokens, (0, 0, 0, pos, 0))
+        start[axis] = pos
+        return jax.lax.dynamic_update_slice(cache, tokens, tuple(start))
     for r in range(cache.shape[1]):
+        start[1], start[axis] = r, pos[r]
         cache = jax.lax.dynamic_update_slice(cache, tokens[:, r: r + 1],
-                                             (0, r, 0, pos[r], 0))
+                                             tuple(start))
     return cache
 
 
 def write_decode_cache(mixer_kind: str, cache: Dict, new: Dict,
                        pos: jax.Array) -> Dict:
     """A block's stacked cache after a decode step, given the step's
-    ``new`` (stacked over groups by the scan): attention keys and values go
-    in at ``pos``, a recurrent state is replaced whole, the rest (cross
-    caches) stays as it was."""
+    ``new`` (stacked over groups by the scan): a leaf with positions
+    (``CACHE_POSITIONS``) takes the new row at ``pos``, a recurrent state is
+    replaced whole, the rest (cross caches) stays as it was."""
     out = dict(cache)
+    axes = CACHE_POSITIONS.get(mixer_kind, {})
     for key, val in new.items():
-        out[key] = (_write_tokens(cache[key], val, pos)
-                    if mixer_kind == "attention" else val)
+        out[key] = (val if key not in axes
+                    else _write_tokens(cache[key], val, pos, axes[key] + 1))
+    return out
+
+
+def pad_positions(mixer_kind: str, cache: Dict, cap: int) -> Dict:
+    """A block's stacked cache from prefill, its leaves with positions
+    (``CACHE_POSITIONS``) padded to the capacity ``cap``."""
+    out = dict(cache)
+    for key, axis in CACHE_POSITIONS.get(mixer_kind, {}).items():
+        if key in cache:
+            pad = [(0, 0)] * cache[key].ndim
+            pad[axis + 1] = (0, cap - cache[key].shape[axis + 1])
+            out[key] = jnp.pad(cache[key], pad)
     return out
 
 
@@ -205,40 +251,91 @@ def write_decode_cache(mixer_kind: str, cache: Dict, new: Dict,
 # ---------------------------------------------------------------------------
 
 
+def segments(cfg, n_layers: Optional[int] = None,
+             pattern: Optional[Tuple[Tuple[str, str], ...]] = None):
+    """[(pattern, groups)] of a stack, in order: the leading dense layers
+    (if the config has them), then the repeating pattern. An explicit
+    ``pattern`` (the encoder's) has no leading layers."""
+    n_layers = n_layers or cfg.n_layers
+    segs = []
+    if pattern is None and cfg.first_k_dense:
+        segs.append((cfg.lead_pattern(), cfg.first_k_dense))
+        n_layers -= cfg.first_k_dense
+    pattern = pattern or cfg.pattern()
+    assert n_layers % len(pattern) == 0, (n_layers, len(pattern))
+    segs.append((pattern, n_layers // len(pattern)))
+    return segs
+
+
+_EXPERT_LEAVES = ("w1", "w2", "w3")
+
+
+def _scan_split(pattern, seg_params: Tuple):
+    """(scanned, whole): the segment's params with the expert weights of its
+    MoE blocks taken out of what the scan slices (``whole``, [G, E, ...]).
+    The grouped matmul reads them where they lie, at the group's index; a
+    slice taken by the scan would be copied first, for a custom call."""
+    scanned, whole = [], []
+    for kinds, p in zip(pattern, seg_params):
+        w = {}
+        if kinds[1] == "moe":
+            mlp = dict(p["mlp"])
+            w = {k: mlp.pop(k) for k in _EXPERT_LEAVES if k in mlp}
+            p = {**p, "mlp": mlp}
+        scanned.append(p)
+        whole.append(w)
+    return tuple(scanned), tuple(whole)
+
+
+def _joined(p: Dict, whole: Dict, i: jax.Array) -> Dict:
+    """A block's params at group ``i`` from the scanned slice and the whole
+    expert weights."""
+    return {**p, "mlp": {**p["mlp"], **whole, "layer": i}} if whole else p
+
+
+def _split(stack: Tuple, segs):
+    """The stack's tuple cut into one tuple per segment."""
+    out, i = [], 0
+    for pattern, _ in segs:
+        out.append(stack[i: i + len(pattern)])
+        i += len(pattern)
+    return out
+
+
 def init_stack(cfg, rng, n_layers: Optional[int] = None, cross: bool = False,
                pattern: Optional[Tuple[Tuple[str, str], ...]] = None) -> Tuple:
-    """Returns a tuple (one entry per period position) of param pytrees with
-    leading group dim G = n_layers / period."""
-    pattern = pattern or cfg.pattern()
-    n_layers = n_layers or cfg.n_layers
-    period = len(pattern)
-    assert n_layers % period == 0, (n_layers, period)
-    g = n_layers // period
+    """Returns a tuple (one entry per period position of each segment) of
+    param pytrees with leading group dim G (the segment's groups)."""
+    segs = segments(cfg, n_layers, pattern)
     out = []
-    for pp, kinds in enumerate(pattern):
-        keys = jax.random.split(jax.random.fold_in(rng, pp), g)
-        out.append(
-            jax.vmap(
-                lambda k_: init_block(cfg, k_, kinds[0], kinds[1], cross=cross)
-            )(keys)
-        )
+    for si, (pat, g) in enumerate(segs):
+        # the repeating pattern (the last segment) draws as it always has
+        salt = (len(segs) - 1 - si) * 10_000
+        for pp, kinds in enumerate(pat):
+            keys = jax.random.split(jax.random.fold_in(rng, salt + pp), g)
+            out.append(
+                jax.vmap(
+                    lambda k_: init_block(cfg, k_, kinds[0], kinds[1], cross=cross)
+                )(keys)
+            )
     return tuple(out)
 
 
 def init_stack_cache(cfg, batch: int, cap: int, n_layers: Optional[int] = None,
                      cross_len: int = 0,
                      pattern: Optional[Tuple[Tuple[str, str], ...]] = None) -> Tuple:
-    pattern = pattern or cfg.pattern()
-    n_layers = n_layers or cfg.n_layers
-    g = n_layers // len(pattern)
-
-    def rep(tree):
+    def rep(tree, g):
         return jax.tree.map(lambda a: jnp.broadcast_to(a[None], (g,) + a.shape), tree)
 
     return tuple(
-        rep(init_block_cache(cfg, kinds[0], batch, cap, cross_len=cross_len))
-        for kinds in pattern
+        rep(init_block_cache(cfg, kinds[0], batch, cap, cross_len=cross_len), g)
+        for pat, g in segments(cfg, n_layers, pattern) for kinds in pat
     )
+
+
+def stack_kinds(cfg, pattern: Optional[Tuple[Tuple[str, str], ...]] = None):
+    """(mixer, mlp) of each entry of the stack's tuple."""
+    return tuple(k for pat, _ in segments(cfg, None, pattern) for k in pat)
 
 
 def apply_stack(
@@ -252,30 +349,37 @@ def apply_stack(
     remat: Optional[bool] = None,
     collect_cache: bool = False,
 ) -> Tuple[jax.Array, Optional[Tuple], jax.Array]:
-    """Scan over layer groups. Returns (x, caches (if collected), aux_sum)."""
-    pattern = pattern or cfg.pattern()
+    """Scan over layer groups, segment by segment. Returns (x, caches (if
+    collected), aux_sum)."""
     remat = cfg.remat_stack if remat is None else remat
+    segs = segments(cfg, None, pattern)
+    aux = jnp.zeros((), jnp.float32)
+    caches = []
+    for (pat, _), seg_params in zip(segs, _split(stack_params, segs)):
+        scanned, whole = _scan_split(pat, seg_params)
 
-    def body(carry, params_g):
-        xc, aux = carry
-        xc = pctx.constrain_tokens(xc)
-        caches = []
-        for pp, kinds in enumerate(pattern):
-            xc, cache, a = apply_block(cfg, params_g[pp], kinds, xc, positions,
-                                       causal=causal, enc_out=enc_out)
+        def body(carry, params_g, pat=pat, whole=whole):
+            xc, aux, i = carry
             xc = pctx.constrain_tokens(xc)
-            caches.append(cache)
-            aux = aux + a
-        out = tuple(caches) if collect_cache else None
-        return (xc, aux), out
+            out = []
+            for pp, kinds in enumerate(pat):
+                xc, cache, a = apply_block(cfg, _joined(params_g[pp], whole[pp], i),
+                                           kinds, xc, positions, causal=causal,
+                                           enc_out=enc_out)
+                xc = pctx.constrain_tokens(xc)
+                out.append(cache)
+                aux = aux + a
+            return (xc, aux, i + 1), (tuple(out) if collect_cache else None)
 
-    if remat:
-        body = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.nothing_saveable
-        )
-    (x, aux), caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                    stack_params)
-    return x, caches, aux
+        if remat:
+            body = jax.checkpoint(
+                body, policy=jax.checkpoint_policies.nothing_saveable
+            )
+        (x, aux, _), seg_caches = jax.lax.scan(
+            body, (x, aux, jnp.zeros((), jnp.int32)), scanned)
+        if collect_cache:
+            caches.extend(seg_caches)
+    return x, (tuple(caches) if collect_cache else None), aux
 
 
 def apply_stack_decode(
@@ -285,27 +389,40 @@ def apply_stack_decode(
     cache: Tuple,
     pos: jax.Array,
     pattern: Optional[Tuple[Tuple[str, str], ...]] = None,
-) -> Tuple[jax.Array, Tuple]:
+    live: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Tuple, jax.Array]:
     """One decode step through the scanned stack. ``pos`` is a scalar (all
     rows at the same depth) or ``[B]`` vector (per-slot depths); it is
-    closed over by the scan body and handled in ``attn.decode_attention``
+    closed over by the scan body and handled in the mixers' decode
     (SSM/xLSTM mixers are position-free recurrences).
 
     The scan reads each group's cache and carries out only what the step
     changes (``apply_block_decode``); the new keys and values of every
     layer are written after it, so a donated cache is updated in place and
-    no layer's slice of it is copied."""
-    pattern = pattern or cfg.pattern()
+    no layer's slice of it is copied. Returns (x, cache, the MoE counters
+    summed over layers, counting the rows ``live`` marks)."""
+    segs = segments(cfg, None, pattern)
+    stats = jnp.zeros((moe_mod.N_STATS,), jnp.int32)
+    out = []
+    for (pat, _), seg_params, seg_cache in zip(segs, _split(stack_params, segs),
+                                               _split(cache, segs)):
 
-    def body(xc, inp):
-        params_g, cache_g = inp
-        new = []
-        for pp, kinds in enumerate(pattern):
-            xc, n = apply_block_decode(cfg, params_g[pp], kinds, xc,
-                                       cache_g[pp], pos)
-            new.append(n)
-        return xc, tuple(new)
+        scanned, whole = _scan_split(pat, seg_params)
 
-    x, new = jax.lax.scan(body, x, (stack_params, cache))
-    return x, tuple(write_decode_cache(kinds[0], c, n, pos)
-                    for kinds, c, n in zip(pattern, cache, new))
+        def body(carry, inp, pat=pat, whole=whole):
+            xc, st, i = carry
+            params_g, cache_g = inp
+            new = []
+            for pp, kinds in enumerate(pat):
+                xc, n, s_ = apply_block_decode(
+                    cfg, _joined(params_g[pp], whole[pp], i), kinds, xc,
+                    cache_g[pp], pos, live)
+                new.append(n)
+                st = st + s_
+            return (xc, st, i + 1), tuple(new)
+
+        (x, stats, _), new = jax.lax.scan(
+            body, (x, stats, jnp.zeros((), jnp.int32)), (scanned, seg_cache))
+        out.extend(write_decode_cache(kinds[0], c, n, pos)
+                   for kinds, c, n in zip(pat, seg_cache, new))
+    return x, tuple(out), stats

@@ -18,27 +18,19 @@ _TP_AXIS: Optional[str] = None
 _TP_SIZE: int = 1
 _SP_SEQ: bool = False  # sequence-parallel activations between blocks
 _MESH = None  # concrete mesh for shard_map code paths
-_MOE_PIN = False  # pin MoE dispatch shardings (refuted optimisation — §Perf)
 
 
 def install(dp_axes: Tuple[str, ...], tp_axis: str = "model",
-            tp_size: int = 1, sp_seq: bool = False, mesh=None,
-            moe_pin: bool = False) -> None:
-    global _DP_AXES, _TP_AXIS, _TP_SIZE, _SP_SEQ, _MESH, _MOE_PIN
-    _DP_AXES, _TP_AXIS, _TP_SIZE, _SP_SEQ, _MESH, _MOE_PIN = (
-        tuple(dp_axes), tp_axis, tp_size, sp_seq, mesh, moe_pin
+            tp_size: int = 1, sp_seq: bool = False, mesh=None) -> None:
+    global _DP_AXES, _TP_AXIS, _TP_SIZE, _SP_SEQ, _MESH
+    _DP_AXES, _TP_AXIS, _TP_SIZE, _SP_SEQ, _MESH = (
+        tuple(dp_axes), tp_axis, tp_size, sp_seq, mesh
     )
 
 
 def clear() -> None:
-    global _DP_AXES, _TP_AXIS, _TP_SIZE, _SP_SEQ, _MESH, _MOE_PIN
-    _DP_AXES, _TP_AXIS, _TP_SIZE, _SP_SEQ, _MESH, _MOE_PIN = (
-        None, None, 1, False, None, False
-    )
-
-
-def moe_pin() -> bool:
-    return _MOE_PIN
+    global _DP_AXES, _TP_AXIS, _TP_SIZE, _SP_SEQ, _MESH
+    _DP_AXES, _TP_AXIS, _TP_SIZE, _SP_SEQ, _MESH = None, None, 1, False, None
 
 
 def mesh():

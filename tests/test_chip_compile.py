@@ -20,7 +20,7 @@ from repro.hw import target_for_device_kind
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.matmul import matmul_pallas
 from repro.kernels.ops import tuned_flash_blocks
-from repro.launch.engine import programs
+from repro.launch.engine import init_state, programs
 from repro.models.model import Model
 
 
@@ -137,3 +137,41 @@ def test_engine_updates_the_cache_in_place(program, slots, cap, one_chip):
     layer_k = slots * cfg.n_kv_heads * cap * cfg.head_dim * 2
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < layer_k
+
+
+def test_moonlight_decode_step_in_place(one_chip, monkeypatch):
+    """The engine's decode step of the benchmark's moonlight-16b-a3b-9l cell
+    at published widths, 64 slots and cap 2048, with the grouped-matmul
+    kernel: the donated state (latent cache and MoE counters) is aliased
+    whole, no temporary reaches one layer's latent cache (a copy of it, or
+    of one layer's expert weights, would), and the kernel's operations
+    carry the name ``moe_gmm``."""
+    import dataclasses
+
+    from repro.kernels import moe_gmm
+
+    monkeypatch.setattr(moe_gmm, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), n_layers=9)
+    model = Model(cfg)
+    slots, cap = 64, 2048
+
+    def abstract(make, *args):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            jax.eval_shape(make, *args))
+
+    state = abstract(lambda: init_state(model, slots, cap))
+    _, decode, _ = programs(model, cap)
+    tok = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = decode.lower(abstract(model.init, jax.random.key(0)), state,
+                            tok, tok).compile()
+    mem = compiled.memory_analysis()
+    cache, counts = state
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    layer_latent = slots * cap * cfg.mla.cache_dim * 2
+    assert cache_bytes == 9 * layer_latent
+    assert cache_bytes <= mem.alias_size_in_bytes <= cache_bytes + 512
+    assert mem.temp_size_in_bytes < layer_latent // 10
+    ops = [ln.strip() for ln in _op_text(compiled).splitlines()
+           if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(ops) == 3 and all(o.startswith("%moe_gmm") for o in ops), ops
