@@ -39,6 +39,13 @@ and the busiest expert's tokens per layer into a counter array held beside
 the cache; ``run`` reads it once, when it returns (``moe_pairs``,
 ``moe_experts_touched``, ``moe_max_load`` in ``stats()``; 0 for a dense
 model, whose programs carry no counters).
+
+Latent-attention counters: for a model with latent attention, each decode
+launch adds, on the host from ``pos``, the latent cache rows one layer's
+``latent_decode`` kernel reads (each slot's blocks below its position, a
+free slot none) and the rows of one layer's whole cache
+(``latent_rows_read``, ``latent_rows_cap`` in ``stats()``; absent for a
+model without latent attention).
 """
 from __future__ import annotations
 
@@ -50,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.latent_decode import block_rows
 from repro.models.moe import N_STATS
 
 
@@ -134,6 +142,16 @@ def _write_slot(live, one, i):
 def counted(model) -> bool:
     """Whether the engine's decode state carries MoE counters."""
     return model.cfg.moe is not None
+
+
+def latent_block(model, cap: int) -> int:
+    """Cache rows the latent-attention kernel reads at a time; 0 for a model
+    without latent attention."""
+    cfg = model.cfg
+    if cfg.mla is None:
+        return 0
+    return block_rows(cap, cfg.mla.cache_dim,
+                      jnp.dtype(cfg.jnp_compute_dtype()).itemsize)
 
 
 def init_state(model, slots: int, cap: int):
@@ -249,6 +267,9 @@ class ContinuousEngine:
             ("run_s", "admit_s", "launch_s", "sync_s", "emit_s"), 0.0)
         self.moe = dict.fromkeys(
             ("moe_pairs", "moe_experts_touched", "moe_max_load"), 0)
+        self._latent_blk = latent_block(model, cap)
+        self.latent = (dict.fromkeys(("latent_rows_read", "latent_rows_cap"), 0)
+                       if self._latent_blk else {})
         self._admitted = 0
 
     # ---------------------------------------------------------------- admit
@@ -340,6 +361,11 @@ class ContinuousEngine:
             self.engine_steps += 1
             self.slot_steps += len(live)
             self.wasted_slot_steps += self.slots - len(live)
+            if self.latent:
+                blk = self._latent_blk
+                self.latent["latent_rows_read"] += int(
+                    ((self.pos + blk - 1) // blk).sum()) * blk
+                self.latent["latent_rows_cap"] += self.slots * self.cap
         with _Phase(self.host_s, "sync_s", "engine.sync"):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))  # one host sync
         with _Phase(self.host_s, "emit_s", "engine.emit"):
@@ -358,4 +384,4 @@ class ContinuousEngine:
                 "cache_reloads": self.cache_reloads,
                 "deadline_truncations": self.deadline_truncations,
                 "cache_in_place": self.cache_in_place,
-                **self.host_s, **self.moe}
+                **self.host_s, **self.moe, **self.latent}

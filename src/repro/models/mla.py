@@ -11,6 +11,9 @@ key (``ckv`` [B, CAP, kv_rank + rope_dim]). Prefill expands them per head
 and attends with the flash kernel (values zero-padded to the key width).
 Decode attends in latent form: the key half of ``wkv_b`` is folded into the
 query and the value half into the output, so the cache is never expanded.
+On a TPU the cache is read by the ``latent_decode`` kernel
+(``kernels/latent_decode.py``), each row's blocks below its position once;
+elsewhere by the einsums below over all ``CAP`` rows, the reference.
 """
 from __future__ import annotations
 
@@ -19,6 +22,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import latent_decode as kernel
 from repro.models import attention as attn
 from repro.models import layers as L
 
@@ -85,12 +89,15 @@ def mla_forward(cfg, p: Dict, x: jax.Array, positions: jax.Array,
     return y.astype(x.dtype), ckv
 
 
-def mla_decode(cfg, p: Dict, x: jax.Array, cache: jax.Array, pos: jax.Array):
-    """One token x [B, 1, D] over ``cache`` [B, CAP, kv_rank + rope_dim] at
-    ``pos`` (scalar, or [B] per-slot depths). The cache is only read: the
-    token attends over its rows ``< pos`` and over its own latent, which
-    stands in for the row at ``pos``. Returns (y [B, 1, D], the token's row
-    [B, 1, kv_rank + rope_dim] for the caller to write at ``pos``)."""
+def mla_decode(cfg, p: Dict, x: jax.Array, cache: jax.Array, pos: jax.Array,
+               layer: jax.Array):
+    """One token x [B, 1, D] over the layer's latent cache at ``pos``
+    (scalar, or [B] per-slot depths): ``cache`` is the whole stack [G, B,
+    CAP, kv_rank + rope_dim] and ``layer`` the layer's index in it. The
+    cache is only read: the token attends over its rows ``< pos`` and over
+    its own latent, which stands in for the row at ``pos``. Returns (y [B,
+    1, D], the token's row [B, 1, kv_rank + rope_dim] for the caller to
+    write at ``pos``)."""
     m, h = cfg.mla, cfg.n_heads
     b = x.shape[0]
     cd = cfg.jnp_compute_dtype()
@@ -109,12 +116,37 @@ def mla_decode(cfg, p: Dict, x: jax.Array, cache: jax.Array, pos: jax.Array):
                        preferred_element_type=jnp.float32)
     qc = jnp.concatenate([q_lat, q[..., m.nope_dim:].astype(jnp.float32)], -1)
     qc = (qc * m.qk_dim ** -0.5).astype(cd)  # [B, H, r + rope]
+    s_new = jnp.einsum("bhc,bc->bh", qc, new[:, 0],
+                       preferred_element_type=jnp.float32)
+    if kernel._on_tpu():
+        acc, top_c, sum_c = kernel.latent_decode(
+            qc, cache, jnp.broadcast_to(pos, (b,)), layer, r)
+        # the token's own latent merged into the kernel's running softmax,
+        # over its whole row and sliced after (sliced first, the row takes a
+        # layout with the batch minor, and each row's write after the scan
+        # copies it)
+        top = jnp.maximum(top_c, s_new)
+        corr, p_new = jnp.exp(top_c - top), jnp.exp(s_new - top)
+        o_lat = corr[..., None] * acc + (
+            p_new[..., None] * new.astype(jnp.float32))[..., :r]
+        o_lat = o_lat / (corr * sum_c + p_new)[..., None]
+    else:
+        o_lat = _attend_reference(qc, cache[layer], new, s_new, row, r, cd)
+    # fold the value half of wkv_b into the output
+    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cd), wkv_b[..., m.nope_dim:],
+                   preferred_element_type=jnp.float32)
+    y = o.reshape(b, 1, h * m.v_dim).astype(cd) @ p["wo"].astype(cd)
+    return y.astype(x.dtype), new
+
+
+def _attend_reference(qc, cache, new, s_new, row, r: int, cd):
+    """The normalised latent [B, H, r] of ``qc`` over the cache's rows
+    below ``row`` and the token's own ``new``, in ``jax.numpy`` over all
+    ``CAP`` rows."""
     s_cache = jnp.einsum("bhc,btc->bht", qc, cache,
                          preferred_element_type=jnp.float32)
     idx = jnp.arange(cache.shape[1])
     s_cache = jnp.where((idx[None, :] < row)[:, None], s_cache, NEG_INF)
-    s_new = jnp.einsum("bhc,bc->bh", qc, new[:, 0],
-                       preferred_element_type=jnp.float32)
     top = jnp.maximum(s_cache.max(-1), s_new)
     p_cache = jnp.exp(s_cache - top[..., None])
     p_new = jnp.exp(s_new - top)
@@ -123,9 +155,4 @@ def mla_decode(cfg, p: Dict, x: jax.Array, cache: jax.Array, pos: jax.Array):
     o_lat = jnp.einsum("bht,btc->bhc", p_cache.astype(cd), cache,
                        preferred_element_type=jnp.float32)
     o_lat = o_lat + p_new[..., None] * new.astype(jnp.float32)
-    o_lat = o_lat[..., :r] / (p_cache.sum(-1) + p_new)[..., None]
-    # fold the value half of wkv_b into the output
-    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cd), wkv_b[..., m.nope_dim:],
-                   preferred_element_type=jnp.float32)
-    y = o.reshape(b, 1, h * m.v_dim).astype(cd) @ p["wo"].astype(cd)
-    return y.astype(x.dtype), new
+    return o_lat[..., :r] / (p_cache.sum(-1) + p_new)[..., None]

@@ -161,9 +161,12 @@ def apply_block_decode(
     x: jax.Array,  # [B, 1, D]
     cache: Dict,
     pos: jax.Array,
+    layer: jax.Array,
     live: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict, jax.Array]:
-    """One token through one block; the block's cache is only read.
+    """One token through one block at group ``layer``; the block's cache is
+    only read. The leaves ``_WHOLE_CACHE`` names are the whole stack's
+    [G, B, ...], the others the group's.
     Returns (x, new, MoE counters): what the step changes in the cache —
     an attention mixer's new key and value (``k``/``v`` [B, Hkv, 1, dh]) or
     a latent mixer's new row (``ckv`` [B, 1, kv_rank + rope_dim]), for
@@ -177,7 +180,8 @@ def apply_block_decode(
                                         cache["v"], pos)
         new = {"k": k, "v": v}
     elif mixer_kind == "mla":
-        y, ckv = mla_mod.mla_decode(cfg, p["mixer"], h, cache["ckv"], pos)
+        y, ckv = mla_mod.mla_decode(cfg, p["mixer"], h, cache["ckv"], pos,
+                                    layer)
         new = {"ckv": ckv}
     elif mixer_kind == "mamba":
         y, new = ssm_mod.mamba_decode(cfg, p["mixer"], h,
@@ -284,6 +288,23 @@ def _scan_split(pattern, seg_params: Tuple):
             p = {**p, "mlp": mlp}
         scanned.append(p)
         whole.append(w)
+    return tuple(scanned), tuple(whole)
+
+
+# Cache leaves a decode kernel reads where they lie in the stack, at the
+# group's index: the scan does not slice them (it would copy the slice
+# first, for the kernel's custom call).
+_WHOLE_CACHE = {"mla": ("ckv",)}
+
+
+def _cache_split(pattern, seg_cache: Tuple):
+    """(scanned, whole): the segment's cache with the leaves
+    ``_WHOLE_CACHE`` names taken out of what the scan slices."""
+    scanned, whole = [], []
+    for kinds, c in zip(pattern, seg_cache):
+        keys = _WHOLE_CACHE.get(kinds[0], ())
+        scanned.append({k: v for k, v in c.items() if k not in keys})
+        whole.append({k: v for k, v in c.items() if k in keys})
     return tuple(scanned), tuple(whole)
 
 
@@ -399,8 +420,10 @@ def apply_stack_decode(
     The scan reads each group's cache and carries out only what the step
     changes (``apply_block_decode``); the new keys and values of every
     layer are written after it, so a donated cache is updated in place and
-    no layer's slice of it is copied. Returns (x, cache, the MoE counters
-    summed over layers, counting the rows ``live`` marks)."""
+    no layer's slice of it is copied. The latent cache is not sliced by the
+    scan at all (``_WHOLE_CACHE``): its kernel reads it at the group's
+    index. Returns (x, cache, the MoE counters summed over layers, counting
+    the rows ``live`` marks)."""
     segs = segments(cfg, None, pattern)
     stats = jnp.zeros((moe_mod.N_STATS,), jnp.int32)
     out = []
@@ -408,21 +431,22 @@ def apply_stack_decode(
                                                _split(cache, segs)):
 
         scanned, whole = _scan_split(pat, seg_params)
+        scanned_c, whole_c = _cache_split(pat, seg_cache)
 
-        def body(carry, inp, pat=pat, whole=whole):
+        def body(carry, inp, pat=pat, whole=whole, whole_c=whole_c):
             xc, st, i = carry
             params_g, cache_g = inp
             new = []
             for pp, kinds in enumerate(pat):
                 xc, n, s_ = apply_block_decode(
                     cfg, _joined(params_g[pp], whole[pp], i), kinds, xc,
-                    cache_g[pp], pos, live)
+                    {**cache_g[pp], **whole_c[pp]}, pos, i, live)
                 new.append(n)
                 st = st + s_
             return (xc, st, i + 1), tuple(new)
 
         (x, stats, _), new = jax.lax.scan(
-            body, (x, stats, jnp.zeros((), jnp.int32)), (scanned, seg_cache))
+            body, (x, stats, jnp.zeros((), jnp.int32)), (scanned, scanned_c))
         out.extend(write_decode_cache(kinds[0], c, n, pos)
                    for kinds, c, n in zip(pat, seg_cache, new))
     return x, tuple(out), stats

@@ -141,16 +141,21 @@ def test_engine_updates_the_cache_in_place(program, slots, cap, one_chip):
 
 def test_moonlight_decode_step_in_place(one_chip, monkeypatch):
     """The engine's decode step of the benchmark's moonlight-16b-a3b-9l cell
-    at published widths, 64 slots and cap 2048, with the grouped-matmul
-    kernel: the donated state (latent cache and MoE counters) is aliased
-    whole, no temporary reaches one layer's latent cache (a copy of it, or
-    of one layer's expert weights, would), and the kernel's operations
-    carry the name ``moe_gmm``."""
+    at published widths, 64 slots and cap 2048, with the grouped-matmul and
+    the latent-attention kernels: the donated state (latent cache and MoE
+    counters) is aliased whole, no temporary reaches a tenth of one layer's
+    latent cache (a copy of it, of the scan's slice of it for the latent
+    kernel, or of one layer's expert weights, would), the kernels'
+    operations carry the names ``moe_gmm`` and ``latent_decode``, one
+    ``latent_decode`` in each latent segment's scan body, and no fusion
+    reads the latent cache (the attention einsums over all ``cap`` rows)."""
     import dataclasses
+    import re
 
-    from repro.kernels import moe_gmm
+    from repro.kernels import latent_decode, moe_gmm
 
     monkeypatch.setattr(moe_gmm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(latent_decode, "_on_tpu", lambda: True)
     cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), n_layers=9)
     model = Model(cfg)
     slots, cap = 64, 2048
@@ -172,6 +177,12 @@ def test_moonlight_decode_step_in_place(one_chip, monkeypatch):
     assert cache_bytes == 9 * layer_latent
     assert cache_bytes <= mem.alias_size_in_bytes <= cache_bytes + 512
     assert mem.temp_size_in_bytes < layer_latent // 10
-    ops = [ln.strip() for ln in _op_text(compiled).splitlines()
+    text = _op_text(compiled)
+    ops = [ln.strip() for ln in text.splitlines()
            if 'custom_call_target="tpu_custom_call"' in ln]
-    assert len(ops) == 3 and all(o.startswith("%moe_gmm") for o in ops), ops
+    gmm = [o for o in ops if o.startswith("%moe_gmm")]
+    latent = [o for o in ops if o.startswith("%latent_decode")]
+    assert len(gmm) == 3 and len(latent) == len(cache) == 2, ops
+    assert len(ops) == len(gmm) + len(latent), ops
+    reads = re.compile(rf"fusion\(.*{slots},{cap},{cfg.mla.cache_dim}\]")
+    assert not [ln for ln in text.splitlines() if reads.search(ln)]

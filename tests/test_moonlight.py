@@ -184,6 +184,39 @@ def test_engine_serves_and_counts(setup):
         <= s["engine_steps"] * moe_layers * 2
 
 
+def test_engine_counts_latent_rows(setup):
+    """Each decode launch adds, for one layer, the rows the latent kernel
+    reads (each slot's blocks below its position, none for a free slot)
+    and the rows of the whole cache."""
+    from repro.launch.engine import latent_block
+
+    cfg, model, params, _ = setup
+    cap, slots = 20, 3
+    blk = latent_block(model, cap)
+    assert blk == 4  # f32 rows of 48: the largest power of two dividing 20
+    rng = np.random.default_rng(5)
+    reqs = [Request(i, rng.integers(0, cfg.vocab, n).tolist(), m)
+            for i, (n, m) in enumerate([(5, 6), (9, 3), (4, 8), (7, 2)])]
+    engine = ContinuousEngine(model, params, slots=slots, cap=cap)
+    launched = []
+    decode = engine._decode
+
+    def record(params, state, tok, pos):
+        launched.append(np.array(pos))  # a copy: it may share the engine's buffer
+        return decode(params, state, tok, pos)
+
+    engine._decode = record
+    engine.run(reqs)
+    s = engine.stats()
+    assert len(launched) == s["engine_steps"] > 0
+    assert any((p == 0).any() for p in launched)  # some launch had a free slot
+    assert s["latent_rows_read"] == sum(int((-(-p // blk)).sum()) * blk
+                                        for p in launched)
+    assert s["latent_rows_cap"] == len(launched) * slots * cap
+    assert sum(int(p.sum()) for p in launched) <= s["latent_rows_read"] \
+        < s["latent_rows_cap"]
+
+
 def test_dense_model_carries_no_counters():
     cfg = get_config("yi-6b").reduced()
     model = Model(cfg)
@@ -193,3 +226,4 @@ def test_dense_model_carries_no_counters():
         model.init_cache(2, 16))
     s = engine.stats()
     assert (s["moe_pairs"], s["moe_experts_touched"], s["moe_max_load"]) == (0, 0, 0)
+    assert not {"latent_rows_read", "latent_rows_cap"} & set(s)
