@@ -74,12 +74,13 @@ class CompileClock:
 
 
 class _CompiledOracle:
-    """The model's prefill and scalar-position decode step, each compiled
-    once, for ``greedy_decode_reference`` (op-by-op dispatch at full width
-    would recompile the layer scan on every call).
+    """The model's prefill and decode step, each compiled once, for
+    ``greedy_decode_reference`` (op-by-op dispatch at full width would
+    recompile the layer scan on every call).
 
-    The request decodes alone, in row 0 of a batch as wide as the engine's
-    (the other rows idle). XLA on the TPU tiles a decode batch of one
+    The request decodes alone, in row 0 of a batch as wide as the engine's,
+    at its own position; the other rows idle at token 0 and position 0, as
+    the engine pins a free slot. XLA on the TPU tiles a decode batch of one
     differently from a batch of two: on a v5e the final bf16 hidden state
     moved by up to 0.125 between them, enough to flip a near-tied greedy
     token, while the engine's per-slot positions left it bit-identical."""
@@ -94,7 +95,8 @@ class _CompiledOracle:
 
         def decode_step(params, cache, token, pos):
             logits, cache = model.decode_step(
-                params, cache, jnp.pad(token, (0, width - 1)), pos)
+                params, cache, jnp.pad(token, (0, width - 1)),
+                jnp.pad(pos, (0, width - 1)))
             return logits[:1], cache
 
         self._prefill = jax.jit(model.prefill, static_argnums=2)
@@ -115,7 +117,8 @@ def _oracle_logits(oracle, params, prompt, tokens, i, cap):
     logits = last[0, 0]
     for t in range(i):
         step, cache = oracle.decode_step(
-            params, cache, jnp.asarray([tokens[t]], jnp.int32), pos + t)
+            params, cache, jnp.asarray([tokens[t]], jnp.int32),
+            jnp.full((1,), pos + t, jnp.int32))
         logits = step[0]
     return logits
 
@@ -146,8 +149,7 @@ def serve_phase(device, target, seed: int) -> None:
                         MAX_NEW) for i in range(N_REQUESTS)]
     cap = PROMPT_LEN + MAX_NEW
     ops.reset_pallas_trace_counts()
-    stats = serve(model, params, requests, slots=SLOTS, cap=cap,
-                  scheduler="continuous")
+    stats = serve(model, params, requests, slots=SLOTS, cap=cap)
     print(f"[chip_smoke] served {len(requests)} requests, {stats['tokens']} "
           f"tokens: {stats['prefills']} prefills, {stats['engine_steps']} "
           f"decode steps on {SLOTS} slots")

@@ -1,4 +1,5 @@
-"""Batched serving example: prefill + lockstep decode over request waves.
+"""Batched serving example: the continuous engine over a queue of requests,
+each slot refilled as its request finishes.
 
     PYTHONPATH=src python examples/serve_batched.py --arch yi-6b --requests 6
 """

@@ -192,11 +192,10 @@ def get_default_cache():
 def refresh_default_cache() -> bool:
     """Hot-reload the default serving snapshot if its content changed.
 
-    Long-running serve processes call this between waves: it re-reads the
-    snapshot header at the installed path (following a ``latest``
-    pointer), compares the stored sha1 against the instance being served,
-    and swaps in a fresh ``ScheduleCache`` — clearing the block-spec
-    memos — when a republish landed. Returns True iff a swap happened
+    Re-reads the snapshot header at the installed path (following a
+    ``latest`` pointer), compares the stored sha1 against the instance
+    being served, and swaps in a fresh ``ScheduleCache`` — clearing the
+    block-spec memos — when a republish landed. Returns True iff a swap happened
     (the new instance starts with zeroed hit/miss counters). While the
     new file is missing, torn, mid-publish, or stale, the current
     instance keeps serving — a failed poll never takes the cache away."""

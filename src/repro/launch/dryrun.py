@@ -141,7 +141,7 @@ def run_cell(
             batch_s = S.infer_batch_specs(cfg, batch, seq)
             b_shard = sh.batch_sharding(batch_s, mesh)
             cap = seq + (cfg.n_frontend_tokens if cfg.frontend == "vision" else 0)
-            step = steps_mod.make_prefill_step(model, cap=cap)
+            step = functools.partial(model.prefill, cap=cap)
             cache_s = jax.eval_shape(step, params_s, batch_s)[0]
             c_shard = sh.cache_sharding(cache_s, mesh)
             jitted = jax.jit(
@@ -157,9 +157,8 @@ def run_cell(
             tok_shard = sh.batch_sharding({"tokens": dspec["tokens"]}, mesh)[
                 "tokens"
             ]
-            step = steps_mod.make_serve_step(model)
             jitted = jax.jit(
-                step,
+                model.decode_step,
                 in_shardings=(p_shard, c_shard, tok_shard, sh.replicated(mesh)),
                 out_shardings=(None, c_shard),
                 donate_argnums=(1,),

@@ -1,10 +1,6 @@
 """Continuous-batching serve engine: a slot state machine.
 
-The wave scheduler (``launch/serve.py``) prefills ``slots`` requests
-together and decodes them in lockstep — a finished request parks its slot
-idle until the slowest request in the wave drains, and short waves are
-padded with zero-prompts that burn full decode FLOPs per step. This module
-replaces that with per-slot scheduling (design notes: README "Serving"):
+Per-slot scheduling (design notes: README "Serving"):
 
 * every slot carries its own position — ``Model.decode_step`` takes a
   ``[B]`` pos vector, so rows at different sequence depths share one
@@ -17,19 +13,15 @@ replaces that with per-slot scheduling (design notes: README "Serving"):
   masked out of every report: ``wasted_slot_steps`` counts exactly those
   slot-steps, which is the quantity continuous batching drives down.
 
-Schedule-snapshot hot reload polls at *admission* boundaries (the moment a
-new request enters the engine) instead of wave boundaries, so a fleet
-republish lands mid-traffic without waiting for a full wave to drain.
-
 Per-request measurement: TTFT (submit -> first token) and end-to-end
 latency, aggregated to p50/p95/p99 by ``latency_summary``.
 
 Host time: every iteration of ``run`` is a profiler step (``engine.step``)
 cut into host spans that nest strictly on the engine's thread (README
-"Tracing the engine"): ``engine.admit`` (``engine.refresh``,
-``engine.prefill``, ``engine.first_token``, ``engine.insert``),
-``engine.launch``, ``engine.sync`` and ``engine.emit``. The spans of a
-request carry its ``rid``. The four phases also add their seconds to
+"Tracing the engine"): ``engine.admit`` (``engine.prefill``,
+``engine.first_token``, ``engine.insert``), ``engine.launch``,
+``engine.sync`` and ``engine.emit``. The spans of a request carry its
+``rid``. The four phases also add their seconds to
 ``stats()`` (``admit_s``, ``launch_s``, ``sync_s``, ``emit_s``), which
 together cover ``run_s``.
 
@@ -114,8 +106,8 @@ def request_stats(requests: List[Request]) -> Dict:
 
 def greedy_decode_reference(model, params, prompt: List[int], max_new: int,
                             cap: int, eos_id: Optional[int] = None) -> List[int]:
-    """One-request-at-a-time greedy decode (scalar-pos path) — the oracle
-    the schedulers must match token-for-token."""
+    """One-request-at-a-time greedy decode (a batch of one, its position a
+    [1] vector) — the oracle the engine must match token-for-token."""
     batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
     cache, pos, last_logits = model.prefill(params, batch, cap)
     tok = int(jnp.argmax(last_logits[0, 0]))
@@ -124,7 +116,8 @@ def greedy_decode_reference(model, params, prompt: List[int], max_new: int,
         if eos_id is not None and out[-1] == eos_id:
             break
         logits, cache = model.decode_step(
-            params, cache, jnp.asarray([out[-1]], jnp.int32), pos + t)
+            params, cache, jnp.asarray([out[-1]], jnp.int32),
+            jnp.full((1,), pos + t, jnp.int32))
         out.append(int(jnp.argmax(logits[0])))
     if eos_id is not None and eos_id in out:
         out = out[: out.index(eos_id) + 1]
@@ -243,12 +236,11 @@ class ContinuousEngine:
         deadline, then the slot frees on the same engine step.
     """
 
-    def __init__(self, model, params, slots: int, cap: int, refresh=None):
+    def __init__(self, model, params, slots: int, cap: int):
         self.model = model
         self.params = params
         self.slots = slots
         self.cap = cap
-        self.refresh = refresh
 
         self._prefill, self._decode, self._insert = programs(model, cap)
         self.cache = init_state(model, slots, cap)  # donated to each call
@@ -260,7 +252,6 @@ class ContinuousEngine:
         self.slot_steps = 0          # slot-steps doing live work
         self.wasted_slot_steps = 0   # slot-steps on free slots
         self.prefills = 0
-        self.cache_reloads = 0
         self.deadline_truncations = 0
         self.cache_in_place = 0      # decodes/inserts given it as donated
         self.host_s = dict.fromkeys(
@@ -270,7 +261,6 @@ class ContinuousEngine:
         self._latent_blk = latent_block(model, cap)
         self.latent = (dict.fromkeys(("latent_rows_read", "latent_rows_cap"), 0)
                        if self._latent_blk else {})
-        self._admitted = 0
 
     # ---------------------------------------------------------------- admit
     def _admit(self, slot_i: int, req: Request) -> None:
@@ -295,7 +285,6 @@ class ContinuousEngine:
         self.tok[slot_i] = tok0
         req.out.append(tok0)
         req.t_first = time.perf_counter() - self._t0
-        self._admitted += 1
         self._maybe_finish(slot_i)
 
     def _maybe_finish(self, slot_i: int) -> None:
@@ -338,14 +327,8 @@ class ContinuousEngine:
     def _step(self, queue: List[Request]) -> None:
         """One iteration of ``run``: refill every free slot, then one decode
         step over the live slots."""
-        # the snapshot poll rides the admission boundary (not the very first
-        # batch — that snapshot was just loaded at startup)
         if queue and any(s.free for s in self._slots):
             with _Phase(self.host_s, "admit_s", "engine.admit"):
-                if self.refresh is not None and self._admitted:
-                    with jax.profiler.TraceAnnotation("engine.refresh"):
-                        if self.refresh():
-                            self.cache_reloads += 1
                 for i, s in enumerate(self._slots):
                     if s.free and queue:
                         self._admit(i, queue.pop())
@@ -381,7 +364,6 @@ class ContinuousEngine:
                 "slot_steps": self.slot_steps,
                 "wasted_slot_steps": self.wasted_slot_steps,
                 "prefills": self.prefills,
-                "cache_reloads": self.cache_reloads,
                 "deadline_truncations": self.deadline_truncations,
                 "cache_in_place": self.cache_in_place,
                 **self.host_s, **self.moe, **self.latent}

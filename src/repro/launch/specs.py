@@ -64,7 +64,7 @@ def abstract_cache(model: Model, batch: int, cap: int):
 def decode_specs(cfg: ArchConfig, batch: int, cap: int) -> Dict[str, Any]:
     return {
         "tokens": sds((batch,), jnp.int32),
-        "pos": sds((), jnp.int32),
+        "pos": sds((batch,), jnp.int32),
     }
 
 

@@ -1,8 +1,7 @@
-"""Step functions (what gets jit-ed, lowered, and dry-run compiled)."""
+"""The training step (what gets jit-ed, lowered, and dry-run compiled)."""
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -86,22 +85,3 @@ def make_train_step(
         return params, opt_state, metrics
 
     return train_step
-
-
-def make_prefill_step(model: Model, cap: int) -> Callable:
-    def prefill_step(params, batch):
-        cache, pos, last_logits = model.prefill(params, batch, cap)
-        return cache, pos, last_logits
-
-    return prefill_step
-
-
-def make_serve_step(model: Model) -> Callable:
-    """One decode step for a whole batch of requests (continuous batching's
-    inner loop): (params, cache, tokens [B], pos) -> (logits, new_cache)."""
-
-    def serve_step(params, cache, tokens, pos):
-        logits, new_cache = model.decode_step(params, cache, tokens, pos)
-        return logits, new_cache
-
-    return serve_step

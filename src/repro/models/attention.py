@@ -169,16 +169,14 @@ def decode_attention(
     x: jax.Array,  # [B, 1, D]
     cache_k: jax.Array,  # [B, Hkv, CAP, dh]
     cache_v: jax.Array,
-    pos: jax.Array,  # scalar i32 (lockstep) or [B] i32 (per-slot depths)
+    pos: jax.Array,  # [B] i32, each row's own depth
     cross: bool = False,
 ) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
     """One-token attention over the cache; returns (y, k_new, v_new).
 
-    ``pos`` is the index where each new token sits. A scalar means every
-    row decodes at the same depth (wave scheduling); a ``[B]`` vector gives
-    each slot its own depth (continuous batching) — RoPE angles and the
-    validity mask are then per-slot, so rows at different sequence lengths
-    share one decode launch.
+    ``pos`` [B] is the index where each row's new token sits: RoPE angles
+    and the validity mask are per row, so rows at different sequence
+    lengths share one decode launch.
 
     The cache is only read: the token attends over the cache's positions
     ``< pos`` and over its own key and value, which stand in for the cache
@@ -214,23 +212,19 @@ def decode_attention(
             knew, vnew = knew + p["bk"].astype(cd), vnew + p["bv"].astype(cd)
         knew = knew.reshape(b, hkv, 1, dh)
         vnew = vnew.reshape(b, hkv, 1, dh).astype(cache_v.dtype)
-        if pos.ndim == 1:
-            # per-row tables [B, 1, dh/2]; lift to [B, 1, 1, dh/2] so they
-            # broadcast over the head axis of q [B, H, 1, dh] / knew
-            sin, cos = L.rope_tables(cfg, pos[:, None].astype(jnp.int32))
-            sin, cos = sin[:, None], cos[:, None]
-            row = pos[:, None]  # [B, 1]: each row's own position
-        else:
-            sin, cos = L.rope_tables(cfg, pos[None].astype(jnp.int32))  # [1, dh/2]
-            row = pos[None, None]
+        # per-row tables [B, 1, dh/2]; lift to [B, 1, 1, dh/2] so they
+        # broadcast over the head axis of q [B, H, 1, dh] / knew
+        sin, cos = L.rope_tables(cfg, pos[:, None].astype(jnp.int32))
+        sin, cos = sin[:, None], cos[:, None]
+        row = pos[:, None]  # [B, 1]: each row's own position
         q = L.apply_rope(q.reshape(b, h, 1, dh), sin, cos).reshape(b, h, dh)
         knew = L.apply_rope(knew, sin, cos).astype(cache_k.dtype)
         # the new token in place of the cache row at pos, selected inside
         # the read: no copy of the cache is made
-        at = (idx[None, :] == row)[:, None, :, None]  # [B|1, 1, cap, 1]
+        at = (idx[None, :] == row)[:, None, :, None]  # [B, 1, cap, 1]
         keys = jnp.where(at, knew, cache_k)
         values = jnp.where(at, vnew, cache_v)
-        valid = (idx[None, :] <= row)[:, None, None]  # [B|1, 1, 1, cap]
+        valid = (idx[None, :] <= row)[:, None, None]  # [B, 1, 1, cap]
 
     qg = q.reshape(b, hkv, g, dh).astype(jnp.float32) * (dh ** -0.5)
     logits = jnp.einsum("bhgd,bhkd->bhgk", qg, keys.astype(jnp.float32))

@@ -91,8 +91,8 @@ def mla_forward(cfg, p: Dict, x: jax.Array, positions: jax.Array,
 
 def mla_decode(cfg, p: Dict, x: jax.Array, cache: jax.Array, pos: jax.Array,
                layer: jax.Array):
-    """One token x [B, 1, D] over the layer's latent cache at ``pos``
-    (scalar, or [B] per-slot depths): ``cache`` is the whole stack [G, B,
+    """One token x [B, 1, D] over the layer's latent cache at ``pos`` [B]
+    (each row's own depth): ``cache`` is the whole stack [G, B,
     CAP, kv_rank + rope_dim] and ``layer`` the layer's index in it. The
     cache is only read: the token attends over its rows ``< pos`` and over
     its own latent, which stands in for the row at ``pos``. Returns (y [B,
@@ -102,12 +102,8 @@ def mla_decode(cfg, p: Dict, x: jax.Array, cache: jax.Array, pos: jax.Array,
     b = x.shape[0]
     cd = cfg.jnp_compute_dtype()
     r = m.kv_rank
-    if pos.ndim == 1:
-        sin, cos = L.rope_tables(cfg, pos[:, None], m.rope_dim)  # [B, 1, rope/2]
-        row = pos[:, None]
-    else:
-        sin, cos = L.rope_tables(cfg, pos[None], m.rope_dim)  # [1, rope/2]
-        row = pos[None, None]
+    sin, cos = L.rope_tables(cfg, pos[:, None], m.rope_dim)  # [B, 1, rope/2]
+    row = pos[:, None]
     q = _queries(cfg, p, x, sin, cos)[:, 0]  # [B, H, qk]
     new = latent(cfg, p, x, sin, cos).astype(cache.dtype)  # [B, 1, r + rope]
     wkv_b = p["wkv_b"].astype(cd).reshape(r, h, m.nope_dim + m.v_dim)
@@ -119,8 +115,7 @@ def mla_decode(cfg, p: Dict, x: jax.Array, cache: jax.Array, pos: jax.Array,
     s_new = jnp.einsum("bhc,bc->bh", qc, new[:, 0],
                        preferred_element_type=jnp.float32)
     if kernel._on_tpu():
-        acc, top_c, sum_c = kernel.latent_decode(
-            qc, cache, jnp.broadcast_to(pos, (b,)), layer, r)
+        acc, top_c, sum_c = kernel.latent_decode(qc, cache, pos, layer, r)
         # the token's own latent merged into the kernel's running softmax,
         # over its whole row and sliced after (sliced first, the row takes a
         # layout with the batch minor, and each row's write after the scan

@@ -5,9 +5,8 @@ Batch schemas (all arrays device-shardable):
   audio:        + {"frames": [B, n_frontend_tokens, D]}     (STUB frontend)
   vlm:          + {"patches": [B, n_frontend_tokens, D]}    (STUB frontend)
 
-Decode state (``DecodeState``) carries the per-layer cache tuple, the
-position (scalar for lockstep waves, ``[B]`` for per-slot continuous
-batching), and (enc-dec only) cross-attention caches built at prefill.
+Decoding takes the per-layer cache tuple (which, for enc-dec, holds the
+cross-attention caches built at prefill) and each row's position, ``[B]``.
 """
 from __future__ import annotations
 
@@ -148,8 +147,7 @@ class Model:
         return caches, jnp.asarray(s_total, jnp.int32), last_logits
 
     def decode_step(self, params, cache, token: jax.Array, pos: jax.Array):
-        """token [B] i32; pos scalar i32 (all rows at the same depth) or
-        [B] i32 (per-slot depths — continuous batching, each row attends,
+        """token [B] i32; pos [B] i32, each row's depth (each row attends,
         ropes and cache-writes at its own position).
         Returns (logits [B, V], new_cache)."""
         logits, new_cache, _ = self.decode_step_counted(params, cache, token, pos)

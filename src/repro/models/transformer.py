@@ -209,14 +209,11 @@ def apply_block_decode(
 def _write_tokens(cache: jax.Array, tokens: jax.Array, pos: jax.Array,
                   axis: int) -> jax.Array:
     """``tokens`` [G, B, ...] (length 1 on ``axis``) written into ``cache``
-    [G, B, ...] (length CAP on ``axis``) at each row's position: one
-    ``dynamic_update_slice`` per row (once at a scalar ``pos``), which XLA
-    does in place in a donated cache. (For the TPU, a scatter over rows and
-    positions compiles to copies of the cache instead.)"""
+    [G, B, ...] (length CAP on ``axis``) at each row's position ``pos``
+    [B]: one ``dynamic_update_slice`` per row, which XLA does in place in a
+    donated cache. (For the TPU, a scatter over rows and positions compiles
+    to copies of the cache instead.)"""
     start = [0] * cache.ndim
-    if pos.ndim == 0:
-        start[axis] = pos
-        return jax.lax.dynamic_update_slice(cache, tokens, tuple(start))
     for r in range(cache.shape[1]):
         start[1], start[axis] = r, pos[r]
         cache = jax.lax.dynamic_update_slice(cache, tokens[:, r: r + 1],
@@ -412,10 +409,9 @@ def apply_stack_decode(
     pattern: Optional[Tuple[Tuple[str, str], ...]] = None,
     live: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Tuple, jax.Array]:
-    """One decode step through the scanned stack. ``pos`` is a scalar (all
-    rows at the same depth) or ``[B]`` vector (per-slot depths); it is
-    closed over by the scan body and handled in the mixers' decode
-    (SSM/xLSTM mixers are position-free recurrences).
+    """One decode step through the scanned stack. ``pos`` [B] holds each
+    row's depth; it is closed over by the scan body and handled in the
+    mixers' decode (SSM/xLSTM mixers are position-free recurrences).
 
     The scan reads each group's cache and carries out only what the step
     changes (``apply_block_decode``); the new keys and values of every

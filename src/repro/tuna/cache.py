@@ -24,7 +24,7 @@ searches (pass ``allow_stale=True`` to keep it, with a warning).
 snapshot names (``<prefix>.<cost-model-version>-<digest>.json``) plus an
 atomically-updated ``latest`` pointer, rebuilt whenever the store content
 or the cost-model version changes, and publishable over a
-``repro.tuna.transport`` channel. Long-running serve processes hot-reload
+``repro.tuna.transport`` channel. A running process picks up a republish
 through ``core.tuner.refresh_default_cache()``.
 """
 from __future__ import annotations
@@ -75,7 +75,7 @@ def read_snapshot_header(path: Optional[str] = None, *,
     the identity fields — ``schema``, ``sha1``, ``cost_model_version``,
     ``count`` — live in the first few KB: slice the text before the
     ``"records"`` key and close the object. This is what makes snapshot
-    revalidation cheap enough to run between serving waves (a full parse
+    revalidation cheap enough to run often (a full parse
     of a large snapshot is exactly the cost hot reload must avoid).
     Falls back to a full parse for pre-header-first files. Raises
     ``ValueError`` when the file is not a snapshot or pointer at all.

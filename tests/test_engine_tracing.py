@@ -21,7 +21,7 @@ PHASES = ("admit_s", "launch_s", "sync_s", "emit_s")
 
 # each span of the engine's loop, and the span it opens inside
 PARENT = {"engine.step": None, "engine.admit": "engine.step",
-          "engine.refresh": "engine.admit", "engine.prefill": "engine.admit",
+          "engine.prefill": "engine.admit",
           "engine.first_token": "engine.admit",
           "engine.insert": "engine.admit", "engine.launch": "engine.step",
           "engine.sync": "engine.step", "engine.emit": "engine.step"}
@@ -42,7 +42,7 @@ def _requests(vocab, spec=SPEC):
 
 
 def _engine(model, params):
-    return ContinuousEngine(model, params, SLOTS, CAP, refresh=lambda: False)
+    return ContinuousEngine(model, params, SLOTS, CAP)
 
 
 @pytest.fixture(scope="module")
@@ -177,7 +177,5 @@ def test_serve_reports_host_split(model_and_params):
     assert split.startswith("per decode step: sync ") and "per admission" in split
     calls = stats["engine_steps"] + stats["prefills"]
     assert split.endswith(f"cache in place in {calls} of {calls} calls")
-    # the wave scheduler keeps no host-time counters
-    wave = serve(model, params, _requests(cfg.vocab), SLOTS, CAP,
-                 scheduler="wave")
-    assert host_split(wave) is None
+    # a run with no steps and no admissions has no split
+    assert host_split(serve(model, params, [], SLOTS, CAP)) is None

@@ -87,7 +87,8 @@ class TestDecodeConsistency:
         cap = S + npfx
         prefix = {**batch, "tokens": batch["tokens"][:, : S - 1]}
         cache, pos, _ = model.prefill(params, prefix, cap)
-        lg, _ = model.decode_step(params, cache, batch["tokens"][:, S - 1], pos)
+        lg, _ = model.decode_step(params, cache, batch["tokens"][:, S - 1],
+                                  jnp.full((B,), pos, jnp.int32))
         tol = DECODE_TOL.get(arch, 2e-4)
         np.testing.assert_allclose(np.asarray(lg), np.asarray(full[:, -1]),
                                    atol=tol * 50, rtol=tol * 10)
@@ -104,7 +105,7 @@ class TestDecodeConsistency:
         outs = []
         for t in range(S):
             lg, cache = model.decode_step(params, cache, batch["tokens"][:, t],
-                                          jnp.asarray(t, jnp.int32))
+                                          jnp.full((B,), t, jnp.int32))
             outs.append(lg)
         dec = jnp.stack(outs, axis=1)
         tol = DECODE_TOL.get(arch, 2e-4)

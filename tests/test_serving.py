@@ -10,7 +10,7 @@ import pytest
 from repro.configs.base import get_config
 from repro.launch.engine import (ContinuousEngine, Request,
                                  greedy_decode_reference, latency_summary)
-from repro.launch.serve import group_into_waves, serve
+from repro.launch.serve import serve
 from repro.models.model import Model
 
 RNG = np.random.default_rng(7)
@@ -30,9 +30,10 @@ def mixed_requests(vocab, spec):
 
 
 class TestVectorPos:
-    def test_decode_step_vector_pos_matches_scalar_calls(self, model_and_params):
-        """decode_step with a [B] pos vector == B independent scalar-pos
-        calls on the per-row cache slices (logits and cache writes)."""
+    def test_decode_step_vector_pos_matches_per_row_calls(self, model_and_params):
+        """decode_step with a [B] pos vector == B independent calls, one row
+        each at its [1] pos, on the per-row cache slices (logits and cache
+        writes)."""
         cfg, model, params = model_and_params
         B, cap = 3, 12
         positions = np.array([2, 7, 0], np.int32)
@@ -50,7 +51,7 @@ class TestVectorPos:
             row_cache = jax.tree.map(lambda a: a[:, i: i + 1], cache)
             lg, nc = model.decode_step(
                 params, row_cache, toks[i: i + 1],
-                jnp.asarray(positions[i], jnp.int32))
+                jnp.asarray(positions[i: i + 1]))
             np.testing.assert_allclose(np.asarray(lg[0]),
                                        np.asarray(vec_logits[i]),
                                        atol=1e-5, rtol=1e-5)
@@ -60,19 +61,6 @@ class TestVectorPos:
                 np.testing.assert_allclose(np.asarray(a, np.float32),
                                            np.asarray(b, np.float32),
                                            atol=1e-5, rtol=1e-5)
-
-    def test_scalar_pos_path_unchanged(self, model_and_params):
-        """Scalar pos must still take the lockstep path (wave fallback)."""
-        cfg, model, params = model_and_params
-        batch = {"tokens": jnp.asarray(RNG.integers(0, cfg.vocab, (2, 8)),
-                                       jnp.int32)}
-        cache, pos, _ = model.prefill(params, batch, 12)
-        toks = jnp.asarray(RNG.integers(0, cfg.vocab, 2), jnp.int32)
-        lg_s, _ = model.decode_step(params, cache, toks, pos)
-        lg_v, _ = model.decode_step(
-            params, cache, toks, jnp.full((2,), int(pos), jnp.int32))
-        np.testing.assert_allclose(np.asarray(lg_s), np.asarray(lg_v),
-                                   atol=1e-5, rtol=1e-5)
 
 
 # a decode step's cache writes, per mixer kind: attention (yi), attention
@@ -90,6 +78,15 @@ WRITE_CONFIGS = {
 WRITE_CAP = 12
 
 
+def _jitted(model):
+    """The same model, its calls jitted."""
+    jitted = dataclasses.replace(model)
+    jitted.prefill = jax.jit(model.prefill, static_argnums=2)
+    jitted.decode_step = jax.jit(model.decode_step)
+    jitted.logits = jax.jit(model.logits)
+    return jitted
+
+
 @pytest.fixture(scope="module", params=list(WRITE_CONFIGS))
 def write_case(request):
     cfg = WRITE_CONFIGS[request.param]()
@@ -100,11 +97,7 @@ def write_case(request):
     frames = (0.1 * rng.standard_normal((3, cfg.n_frontend_tokens,
                                          cfg.d_model))
               if cfg.encoder_decoder else None)
-    jitted = dataclasses.replace(model)  # the same model, its calls jitted
-    jitted.prefill = jax.jit(model.prefill, static_argnums=2)
-    jitted.decode_step = jax.jit(model.decode_step)
-    jitted.logits = jax.jit(model.logits)
-    return cfg, jitted, params, seqs, frames
+    return cfg, _jitted(model), params, seqs, frames
 
 
 def _inputs(cfg, seqs, frames, rows, length):
@@ -131,17 +124,17 @@ def _row_cache(model, params, seqs, frames, r, p):
 
 
 def _check_step(cfg, model, params, seqs, frames, cache, pos, live):
-    """One decode step at ``pos`` over ``cache``: in ``k``/``v`` it writes
-    exactly one position per row and layer and leaves every other element
-    bitwise as it was; recurrent states step; cross caches pass through;
-    the logits of the ``live`` rows match the full-sequence forward."""
-    pos = np.asarray(pos, np.int32)
-    rows_pos = np.broadcast_to(pos, jax.tree.leaves(cache)[0].shape[1:2])
+    """One decode step at ``pos`` [B] over ``cache``: in ``k``/``v`` it
+    writes exactly one position per row and layer and leaves every other
+    element bitwise as it was; recurrent states step; cross caches pass
+    through; the logits of the ``live`` rows match the full-sequence
+    forward."""
+    rows_pos = np.asarray(pos, np.int32)
     toks = np.array([seqs[r, p] if r in live else 0
                      for r, p in enumerate(rows_pos)], np.int32)
     old = jax.tree.map(np.asarray, cache)
     logits, new = model.decode_step(params, cache, jnp.asarray(toks),
-                                    jnp.asarray(pos))
+                                    jnp.asarray(rows_pos))
     full = model.logits(params, _inputs(cfg, seqs, frames, sorted(live),
                                         WRITE_CAP))
     for i, r in enumerate(sorted(live)):
@@ -198,94 +191,89 @@ class TestInPlaceWrite:
         _check_step(cfg, model, params, seqs, frames, cache, pos,
                     live={0, 1, 2})
 
-    def test_scalar_pos(self, write_case):
-        """The wave path: every row at one position."""
+    def test_uniform_pos(self, write_case):
+        """A batched prefill of equal lengths, then one step with every row
+        at the same position."""
         cfg, model, params, seqs, frames = write_case
         cache, pos, _ = model.prefill(
             params, _inputs(cfg, seqs, frames, [0, 1, 2], 5), WRITE_CAP)
-        _check_step(cfg, model, params, seqs, frames, cache, int(pos),
+        _check_step(cfg, model, params, seqs, frames, cache, [int(pos)] * 3,
                     live={0, 1, 2})
 
 
 class TestGreedyParity:
-    """Acceptance: continuous output is token-for-token identical to the
-    wave scheduler and to one-request-at-a-time sequential decode, across
-    mixed prompt lengths and mixed max_new."""
+    """Acceptance: continuous output is token-for-token identical to
+    one-request-at-a-time sequential decode, across mixed prompt lengths and
+    mixed max_new, for attention and for the recurrent mixers."""
 
     SPEC = [(4, 3), (8, 6), (4, 5), (8, 2), (12, 4), (4, 6), (12, 7)]
 
-    def test_continuous_matches_wave_and_sequential(self, model_and_params):
-        cfg, model, params = model_and_params
+    @pytest.mark.parametrize("name", ["yi_6b", "mamba", "xlstm"])
+    def test_continuous_matches_sequential(self, name):
+        cfg = WRITE_CONFIGS[name]()
+        model = Model(cfg)
+        params = model.init(jax.random.key(0))
         base = mixed_requests(cfg.vocab, self.SPEC)
         cap = max(len(r.prompt) + r.max_new for r in base) + 2
 
         cont = copy.deepcopy(base)
-        serve(model, params, cont, slots=3, cap=cap, scheduler="continuous")
-        wave = copy.deepcopy(base)
-        serve(model, params, wave, slots=3, cap=cap, scheduler="wave")
+        serve(model, params, cont, slots=3, cap=cap)
 
         for r in cont:
             assert len(r.out) == r.max_new
-        cont_out = {r.rid: r.out for r in cont}
-        assert cont_out == {r.rid: r.out for r in wave}
-        seq_out = {r.rid: greedy_decode_reference(model, params, r.prompt,
+        oracle = _jitted(model)
+        seq_out = {r.rid: greedy_decode_reference(oracle, params, r.prompt,
                                                   r.max_new, cap)
                    for r in base}
-        assert cont_out == seq_out
+        assert {r.rid: r.out for r in cont} == seq_out
 
 
 class TestAccounting:
-    def test_wave_pad_slots_reported_as_waste(self, model_and_params):
-        """A 3-request wave on a 4-slot engine: the pad row's decode work
-        must land in wasted_slot_steps, never in slot_steps."""
+    def test_free_slot_reported_as_waste(self, model_and_params):
+        """3 requests on a 4-slot engine: the free slot's decode work lands
+        in wasted_slot_steps, never in slot_steps."""
         cfg, model, params = model_and_params
         reqs = mixed_requests(cfg.vocab, [(6, 5)] * 3)
-        stats = serve(model, params, reqs, slots=4, cap=16, scheduler="wave")
-        # 4 decode launches (max_new-1) x 1 pad slot; all requests live
-        # the whole wave, so no finished-slot waste on top
+        stats = serve(model, params, reqs, slots=4, cap=16)
+        # 4 decode launches (max_new-1), all three requests live throughout
         assert stats["engine_steps"] == 4
         assert stats["slot_steps"] == 4 * 3
         assert stats["wasted_slot_steps"] == 4
+        assert (stats["slot_steps"] + stats["wasted_slot_steps"]
+                == stats["engine_steps"] * 4)
         assert stats["tokens"] == 15
 
-    def test_wave_finished_slots_reported_as_waste(self, model_and_params):
-        """Mixed max_new in one wave: the short request's idle tail counts
-        as waste while the long one drains."""
+    def test_freed_slot_refilled_before_it_is_waste(self, model_and_params):
+        """A short request's slot is refilled from the queue on the step it
+        frees; it counts as waste only once the queue is empty."""
         cfg, model, params = model_and_params
-        reqs = mixed_requests(cfg.vocab, [(6, 2), (6, 6)])
-        stats = serve(model, params, reqs, slots=2, cap=16, scheduler="wave")
-        # 5 decode launches; request 0 is live for 1 of them
+        reqs = mixed_requests(cfg.vocab, [(6, 2), (6, 6), (6, 3)])
+        stats = serve(model, params, reqs, slots=2, cap=16)
+        # request 0 frees slot 0 after 1 step; request 2 takes it for the
+        # next 2; request 1 then drains alone for its last 2 steps
+        assert stats["prefills"] == 3
         assert stats["engine_steps"] == 5
-        assert stats["slot_steps"] == 5 + 1
-        assert stats["wasted_slot_steps"] == 4
+        assert stats["slot_steps"] == 1 + 5 + 2
+        assert stats["wasted_slot_steps"] == 2
+        assert (stats["slot_steps"] + stats["wasted_slot_steps"]
+                == stats["engine_steps"] * 2)
         assert all(r.t_first is not None and r.t_done is not None
                    for r in reqs)
+        assert reqs[2].t_first >= reqs[0].t_done
 
-    def test_continuous_beats_wave_on_waste(self, model_and_params):
-        """The acceptance inequality on a mixed workload: strictly fewer
-        wasted slot-steps, same tokens."""
+    def test_tokens_and_latency_report(self, model_and_params):
+        """A mixed workload: every request gets its max_new tokens, and the
+        latency report has its shape."""
         cfg, model, params = model_and_params
         spec = [(4, 2), (4, 8), (8, 3), (8, 8), (4, 5), (8, 2)]
-        base = mixed_requests(cfg.vocab, spec)
-        cap = max(len(r.prompt) + r.max_new for r in base) + 2
-        wave = copy.deepcopy(base)
-        sw = serve(model, params, wave, slots=2, cap=cap, scheduler="wave")
-        cont = copy.deepcopy(base)
-        sc = serve(model, params, cont, slots=2, cap=cap,
-                   scheduler="continuous")
-        assert sc["tokens"] == sw["tokens"] == sum(m for _, m in spec)
-        assert sc["wasted_slot_steps"] < sw["wasted_slot_steps"]
-        # latency report shape
-        for s in (sw, sc):
-            for key in ("ttft_s", "latency_s"):
-                assert set(s[key]) == {"p50", "p95", "p99", "mean"}
-            assert len(s["requests"]) == len(spec)
-
-    def test_group_into_waves_buckets_by_length(self, model_and_params):
-        cfg, _, _ = model_and_params
-        reqs = mixed_requests(cfg.vocab, [(4, 1), (8, 1), (4, 1), (4, 1)])
-        waves = group_into_waves(reqs, slots=2)
-        assert [[r.rid for r in w] for w in waves] == [[0, 2], [3], [1]]
+        reqs = mixed_requests(cfg.vocab, spec)
+        cap = max(len(r.prompt) + r.max_new for r in reqs) + 2
+        stats = serve(model, params, reqs, slots=2, cap=cap)
+        assert stats["tokens"] == sum(m for _, m in spec)
+        assert [len(r.out) for r in reqs] == [m for _, m in spec]
+        for key in ("ttft_s", "latency_s"):
+            assert set(stats[key]) == {"p50", "p95", "p99", "mean"}
+        assert len(stats["requests"]) == len(spec)
 
 
 class TestSlotLifecycle:
@@ -299,8 +287,7 @@ class TestSlotLifecycle:
         eos = ref[2]  # cut request 0 at its third emitted token
         reqs = copy.deepcopy(base)
         reqs[0].eos_id = eos
-        stats = serve(model, params, reqs, slots=2, cap=cap,
-                      scheduler="continuous")
+        stats = serve(model, params, reqs, slots=2, cap=cap)
         assert reqs[0].out[-1] == eos
         assert len(reqs[0].out) <= 3
         assert reqs[0].out == ref[: len(reqs[0].out)]
@@ -314,27 +301,11 @@ class TestSlotLifecycle:
         cfg, model, params = model_and_params
         reqs = mixed_requests(cfg.vocab, [(6, 50), (6, 4)])
         reqs[0].deadline_s = 0.0
-        stats = serve(model, params, reqs, slots=1, cap=64,
-                      scheduler="continuous")
+        stats = serve(model, params, reqs, slots=1, cap=64)
         assert reqs[0].truncated and len(reqs[0].out) == 1
         assert reqs[0].t_done is not None
         assert len(reqs[1].out) == 4 and not reqs[1].truncated
         assert stats["deadline_truncations"] == 1
-
-    def test_refresh_polled_at_admission_boundary(self, model_and_params):
-        """The snapshot poll rides admissions, not the first batch."""
-        cfg, model, params = model_and_params
-        calls = []
-
-        def refresh():
-            calls.append(True)
-            return len(calls) == 1
-
-        reqs = mixed_requests(cfg.vocab, [(6, 3)] * 4)
-        stats = serve(model, params, reqs, slots=2, cap=16,
-                      scheduler="continuous", refresh=refresh)
-        assert calls  # polled for the second admission batch
-        assert stats["cache_reloads"] == 1
 
 
 def test_latency_summary_percentiles():

@@ -88,7 +88,7 @@ class TestTrainEndToEnd:
 
 
 class TestServeEndToEnd:
-    def test_wave_batched_serving(self):
+    def test_batched_serving(self):
         cfg = get_config("yi_6b").reduced()
         model = Model(cfg)
         params = model.init(jax.random.key(0))
@@ -112,48 +112,6 @@ class TestServeEndToEnd:
         batch = {"tokens": jnp.asarray([prompt], jnp.int32)}
         logits = model.logits(params, {**batch, "labels": batch["tokens"]})
         assert reqs[0].out[0] == int(jnp.argmax(logits[0, -1]))
-
-
-class TestServeHotReload:
-    def test_republished_snapshot_lands_between_waves(self, tmp_path):
-        """Acceptance: a running serve loop observes a republished schedule
-        snapshot at a wave boundary — new records served, a fresh cache
-        instance (hit counters reset) — without restarting the process."""
-        from repro.core import tuner
-        from repro.tuna.cache import SnapshotManager
-        from repro.tuna.db import ScheduleDatabase, ScheduleRecord
-
-        db = ScheduleDatabase(str(tmp_path / "db.jsonl"))
-        db.add(ScheduleRecord(op="warm[]", target="tpu_v5e",
-                              config={"bm": 64}, score=2.0))
-        mgr = SnapshotManager(db.path, str(tmp_path / "snaps"))
-        mgr.ensure()
-        tuner.set_default_cache(mgr.latest_path)
-        first = tuner.get_default_cache()
-        assert first.best("warm[]", "tpu_v5e") is not None
-
-        cfg = get_config("yi_6b").reduced()
-        model = Model(cfg)
-        params = model.init(jax.random.key(0))
-        rng = np.random.default_rng(0)
-        reqs = [Request(i, list(rng.integers(0, cfg.vocab, 8)), 4)
-                for i in range(4)]  # slots=2 -> two waves, one poll between
-
-        def refresh():
-            # another host re-tunes and republishes while we serve wave 1
-            if tuner.get_default_cache() is first:
-                db.add(ScheduleRecord(op="fresh[]", target="tpu_v5e",
-                                      config={"bm": 128}, score=1.0))
-                mgr.ensure()
-            return tuner.refresh_default_cache()
-
-        stats = serve(model, params, reqs, slots=2, cap=16, refresh=refresh)
-        assert stats["cache_reloads"] == 1
-        assert all(len(r.out) == 4 for r in reqs)
-        swapped = tuner.get_default_cache()
-        assert swapped is not first  # fresh instance: counters reset
-        assert swapped.best("fresh[]", "tpu_v5e").config == {"bm": 128}
-        assert swapped.best("warm[]", "tpu_v5e") is not None
 
 
 class TestElastic:
